@@ -15,13 +15,14 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.api import SoftmaxHead, fit
+from repro.api import fit
 from repro.configs import get_config
 from repro.data.pipeline import batch_iterator_for
 from repro.data.synthetic import SyntheticLM
-from repro.models import api
 from repro.optim import cosine_schedule, make_optimizer
 from repro.sharding.rules import local_ctx
+from repro.train.step import make_eval_fn
+from repro.utils.compile_cache import enable_compile_cache
 
 PRESETS = {
     # name: (d_model, layers, heads, kv, d_ff, vocab, seq, batch)
@@ -42,6 +43,7 @@ def main():
     ap.add_argument("--m", type=int, default=128)
     ap.add_argument("--checkpoint-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     d, nl, nh, nkv, ff, vocab, seq, batch = PRESETS[args.preset]
     cfg = dataclasses.replace(
@@ -64,15 +66,11 @@ def main():
     print(f"chain entropy (loss floor): {lm_task.chain_entropy():.4f}")
 
     eval_batch = next(data)
-    # The dense oracle through the same facade the train step uses:
-    # estimator="full" needs no sampler state and no key.
-    eval_head = SoftmaxHead(dataclasses.replace(cfg, estimator="full"))
+    # The dense oracle (estimator="full") through the train step's island.
+    eval_fn = jax.jit(make_eval_fn(cfg, ctx))
 
-    @jax.jit
     def eval_loss(params):
-        h, labels, _ = api.backbone_hidden(params, eval_batch, cfg, ctx)
-        return jnp.mean(eval_head.loss(api.head_table(params, cfg), h,
-                                       labels))
+        return eval_fn(params, eval_batch)
 
     t0 = time.time()
     res = fit(cfg, ctx, opt, data, steps=args.steps, log_every=20,

@@ -347,18 +347,14 @@ def leaf_logits(stats: HierarchyStats, kernel: SamplingKernel, hq: Array,
     hq: (T, r) projected queries; leaf_idx: (T, m) sampled leaf indices
     -> (T, m, leaf_size) log kernel scores.
     """
-    t, m = leaf_idx.shape
     b = stats.leaf_size
-    rows = stats.wq[leaf_idx]  # (T, m, B, r)
     if use_kernels:
         from repro.kernels import ops
-        flat_rows = rows.reshape(t * m, b, -1)
-        flat_h = jnp.repeat(hq, m, axis=0)  # (T*m, r), row t repeated m times
-        scores = ops.leaf_scores(flat_h, flat_rows,
-                                 alpha=kernel.alpha).reshape(t, m, b)
+        # the kernel fetches each drawn leaf by block index: no gather
+        scores = ops.leaf_scores(hq, stats.wq, leaf_idx, alpha=kernel.alpha)
     else:
-        dots = jnp.einsum("tmbr,tr->tmb", rows, hq)
-        scores = kernel.of_dot(dots)
+        rows = stats.wq[leaf_idx]  # (T, m, B, r)
+        scores = kernel.of_dot(jnp.einsum("tmbr,tr->tmb", rows, hq))
     ids = leaf_idx[..., None] * b + jnp.arange(b)
     scores = jnp.where(ids < stats.n_valid, scores, 0.0)
     return jnp.where(scores > 0, jnp.log(jnp.maximum(scores, 1e-30)),
@@ -688,15 +684,12 @@ def leaf_logits_exp(stats: FeatureStats, hq: Array, leaf_idx: Array,
     ``use_kernels``.  hq: (T, d) raw queries; leaf_idx: (T, m) ->
     (T, m, leaf_size) log scores, padding masked to -inf.
     """
-    t, m = leaf_idx.shape
     b = stats.leaf_size
-    rows = stats.wq[leaf_idx]  # (T, m, B, d)
     if use_kernels:
         from repro.kernels import ops
-        flat_rows = rows.reshape(t * m, b, -1)
-        flat_h = jnp.repeat(hq, m, axis=0)
-        dots = ops.leaf_dots(flat_h, flat_rows).reshape(t, m, b)
+        dots = ops.leaf_dots(hq, stats.wq, leaf_idx)
     else:
+        rows = stats.wq[leaf_idx]  # (T, m, B, d)
         dots = jnp.einsum("tmbr,tr->tmb", rows, hq)
     logit = dots / jnp.asarray(tau, jnp.float32)
     ids = leaf_idx[..., None] * b + jnp.arange(b)

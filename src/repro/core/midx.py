@@ -256,21 +256,17 @@ def member_log_scores(stats: MidxStats, kernel: SamplingKernel, h: Array,
 
     h: (T, d); lists: (T, m) drawn list ids -> (T, m, L) log K(<h, w_i>)
     with padding slots at -inf.  The (T*m, L, d) gathered-row dot + kernel
-    hot loop routes through the ``midx_member_scores`` Pallas kernel."""
+    hot loop routes through the ``leaf_scores`` Pallas kernel, which
+    fetches each drawn list by block index (no (T, m, L, d) gather)."""
     if use_kernels is None:
         use_kernels = jax.default_backend() == "tpu"
-    t, m = lists.shape
     leaf = stats.list_size
-    rows = stats.wq[lists]                       # (T, m, L, d)
     h32 = h.astype(jnp.float32)
     if use_kernels:
         from repro.kernels import ops
-        flat_rows = rows.reshape(t * m, leaf, -1)
-        flat_h = jnp.repeat(h32, m, axis=0)
-        scores = ops.midx_member_scores(flat_h, flat_rows,
-                                        alpha=kernel.alpha
-                                        ).reshape(t, m, leaf)
+        scores = ops.leaf_scores(h32, stats.wq, lists, alpha=kernel.alpha)
     else:
+        rows = stats.wq[lists]                   # (T, m, L, d)
         scores = kernel.of_dot(jnp.einsum("tmld,td->tml", rows, h32))
     pos = lists[..., None] * leaf + jnp.arange(leaf)    # packed positions
     scores = jnp.where(pos < stats.n_valid, scores, 0.0)

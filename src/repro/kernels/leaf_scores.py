@@ -1,25 +1,27 @@
-"""Pallas kernel: per-draw within-leaf scores — the leaf level of both the
-level-synchronous sampling descent (DESIGN.md §2.6) and the serving-side
-beam retrieval (DESIGN.md §5).
+"""Pallas kernel: per-draw within-leaf scores — the leaf level of the
+level-synchronous sampling descent (DESIGN.md §2.6), the MIDX within-list
+step (DESIGN.md §2.9) and the serving-side beam retrieval (DESIGN.md §5).
 
 Two modes over the same body (one VMEM schedule, one contraction):
 
-    kernel mode:  scores[g, b] = alpha * (rows[g, b, :] . h[g, :])^2 + 1
+    kernel mode:  scores[t, j, b] = alpha * (table[idx[t, j], b, :] . h[t, :])^2 + 1
                   — the paper's quadratic kernel K (§3.3), used by the
-                  within-leaf categorical of the sampler.
-    dot mode:     scores[g, b] = rows[g, b, :] . h[g, :]
+                  within-leaf categorical of the samplers.
+    dot mode:     scores[t, j, b] = table[idx[t, j], b, :] . h[t, :]
                   — the raw logit <h, w>, used by ``serve/retrieval.py`` to
                   score surviving leaves exactly for top-k MIPS decode.
 
-for G gathered leaf blocks rows: (G, B, r), one query per draw h: (G, r).
-Grid is one dimension of G tiles; each step loads a (Gt, B, r) block tile and
-its (Gt, r) query tile into VMEM.  The contraction is a batched matvec —
-elementwise multiply + lane reduction on the VPU (B*r flops per draw; the MXU
-has nothing to batch over since every draw owns a distinct leaf block).
-Padding rows inside a leaf are zero, so they score exactly alpha*0+1 (kernel
-mode) or 0 (dot mode); the callers (``hierarchy.leaf_logits`` /
-``retrieval.topk``) mask them out with their ``n_valid`` grids — this kernel
-and its ops.py wrappers return raw scores.
+for a leaf table (L, B, r), queries h: (T, r) and per-query leaf ids
+idx: (T, m).  The gather IS the block fetch: ``idx`` is scalar-prefetched
+and the table's block index map picks leaf ``idx[t, j]`` (leading dim
+squeezed), so the (T, m, B, r) gathered tensor never exists in HBM.  Grid
+is (T, B tiles, m): each step loads one (Bt, r) leaf tile and dots it
+against its query on the VPU (each draw owns a distinct leaf, so there is
+nothing for the MXU to batch over).  The (m, Bt) output block stays
+resident across the innermost draw axis and each step writes its own row.
+Padding rows inside a leaf are zero, so they score exactly alpha*0+1
+(kernel mode) or 0 (dot mode); the callers mask them with their ``n_valid``
+grids — this kernel and its ops.py wrappers return raw scores.
 """
 from __future__ import annotations
 
@@ -28,39 +30,49 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
 
-def _leaf_scores_kernel(alpha, square, h_ref, rows_ref, out_ref):
-    h = h_ref[...].astype(jnp.float32)          # (Gt, r)
-    rows = rows_ref[...].astype(jnp.float32)    # (Gt, B, r)
-    dots = jnp.sum(rows * h[:, None, :], axis=-1)  # (Gt, B)
-    out_ref[...] = alpha * dots * dots + 1.0 if square else dots
+def _leaf_scores_kernel(alpha, square, idx_ref, h_ref, rows_ref, out_ref):
+    j = pl.program_id(2)
+    h = h_ref[...].astype(jnp.float32)              # (1, r)
+    rows = rows_ref[...].astype(jnp.float32)        # (Bt, r)
+    dots = jnp.sum(rows[None] * h[:, None, :], axis=-1)   # (1, Bt)
+    out_ref[pl.ds(j, 1), :] = alpha * dots * dots + 1.0 if square else dots
 
 
 @functools.partial(
-    jax.jit, static_argnames=("alpha", "square", "g_tile", "interpret"))
-def leaf_scores(h: Array, rows: Array, *, alpha: float = 100.0,
-                square: bool = True, g_tile: int = 128,
+    jax.jit, static_argnames=("alpha", "square", "b_tile", "interpret"))
+def leaf_scores(h: Array, table: Array, idx: Array, *, alpha: float = 100.0,
+                square: bool = True, b_tile: int | None = None,
                 interpret: bool = False) -> Array:
-    """h: (G, r); rows: (G, B, r) -> (G, B) fp32 scores.
+    """h: (T, r); table: (L, B, r); idx: (T, m) leaf ids -> (T, m, B) fp32.
 
     ``square=True`` gives quadratic-kernel scores alpha*dot^2+1;
-    ``square=False`` gives raw dots (alpha is ignored).
-    G must divide by g_tile (ops.py pads)."""
-    g, r = h.shape
-    b = rows.shape[1]
-    assert g % g_tile == 0, (g, g_tile)
+    ``square=False`` gives raw dots (alpha is ignored).  B must divide by
+    ``b_tile`` (ops.py picks it from a VMEM budget)."""
+    t, r = h.shape
+    _, b, _ = table.shape
+    m = idx.shape[1]
+    b_tile = b_tile or b
+    assert b % b_tile == 0, (b, b_tile)
     kernel = functools.partial(_leaf_scores_kernel, alpha, square)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(t, b // b_tile, m),
+        in_specs=[
+            pl.BlockSpec((None, 1, r), lambda i, c, j, idx_ref: (i, 0, 0)),
+            pl.BlockSpec((None, b_tile, r),
+                         lambda i, c, j, idx_ref: (idx_ref[i * m + j], c, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, m, b_tile),
+                               lambda i, c, j, idx_ref: (i, 0, c)),
+    )
     return pl.pallas_call(
         kernel,
-        grid=(g // g_tile,),
-        in_specs=[
-            pl.BlockSpec((g_tile, r), lambda i: (i, 0)),
-            pl.BlockSpec((g_tile, b, r), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((g_tile, b), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, b), jnp.float32),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t, m, b), jnp.float32),
         interpret=interpret,
-    )(h, rows)
+    )(idx.reshape(-1).astype(jnp.int32), h.reshape(t, 1, r), table)

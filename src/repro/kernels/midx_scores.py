@@ -1,8 +1,7 @@
-"""Pallas kernels for the MIDX sampler's two-stage hot loop (DESIGN.md §2.9).
+"""Pallas kernel for the MIDX sampler's stage-1 hot loop (DESIGN.md §2.9).
 
-Stage 1 — codeword-pair masses.  Every posting list j quantizes to the
-codeword pair (a1_j, a2_j) of the c1 x c2 cross-product; its sampling mass
-is
+Codeword-pair masses.  Every posting list j quantizes to the codeword pair
+(a1_j, a2_j) of the c1 x c2 cross-product; its sampling mass is
 
     mass[t, j] = cnt_j * (alpha * <h_t, c1[a1_j] + c2[a2_j]>^2 + 1)
 
@@ -14,16 +13,9 @@ expansion is recomputed each call and never stored).  The kernel fuses the
 pass: grid (T tiles x P tiles), one MXU contraction h @ ct^T per step, and
 the (T, P) dot tensor never round-trips through HBM.
 
-Stage 2 — posting-list member scores.  For G gathered (query, draw) pairs,
-
-    scores[g, l] = alpha * (rows[g, l, :] . h[g, :])^2 + 1
-
-— the exact within-list quadratic kernel over each draw's posting list
-rows: (G, L, d).  Same VPU-batched-matvec schedule as ``leaf_scores``
-(each draw owns a distinct list, so there is nothing for the MXU to batch
-over).  Padding rows are zero and score exactly 1; the caller
-(``core/midx.member_log_scores``) masks them against its packed-position
-grid — these kernels return raw scores.
+Stage 2 (exact within-list member scores) is the quadratic-kernel mode of
+``leaf_scores``: the posting lists are a (P, L, d) leaf table and each draw
+gathers its list by block index.
 """
 from __future__ import annotations
 
@@ -39,11 +31,12 @@ Array = jax.Array
 def _pair_masses_kernel(alpha, h_ref, ct_ref, cnt_ref, out_ref):
     h = h_ref[...].astype(jnp.float32)          # (Tt, d)
     ct = ct_ref[...].astype(jnp.float32)        # (Pt, d)
-    cnt = cnt_ref[...].astype(jnp.float32)      # (Pt,)
+    cnt = cnt_ref[...].astype(jnp.float32)      # (1, Pt)
     dots = jax.lax.dot_general(
         h, ct, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)      # (Tt, Pt)
-    out_ref[...] = cnt[None, :] * (alpha * dots * dots + 1.0)
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)     # (Tt, Pt)
+    out_ref[...] = cnt * (alpha * dots * dots + 1.0)
 
 
 @functools.partial(jax.jit,
@@ -55,7 +48,8 @@ def midx_pair_masses(h: Array, ct: Array, cnt: Array, *,
     -> (T, P) fp32 stage-1 sampling masses.
 
     T must divide by t_tile and P by p_tile (ops.py pads; padded lists
-    carry cnt 0 and therefore mass exactly 0)."""
+    carry cnt 0 and therefore mass exactly 0).  ``cnt`` rides lane-dense
+    as one (1, P) row."""
     t, d = h.shape
     p = ct.shape[0]
     assert t % t_tile == 0 and p % p_tile == 0, (t, p, t_tile, p_tile)
@@ -66,39 +60,9 @@ def midx_pair_masses(h: Array, ct: Array, cnt: Array, *,
         in_specs=[
             pl.BlockSpec((t_tile, d), lambda i, j: (i, 0)),
             pl.BlockSpec((p_tile, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((p_tile,), lambda i, j: (j,)),
+            pl.BlockSpec((1, p_tile), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((t_tile, p_tile), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, p), jnp.float32),
         interpret=interpret,
-    )(h, ct, cnt)
-
-
-def _member_scores_kernel(alpha, h_ref, rows_ref, out_ref):
-    h = h_ref[...].astype(jnp.float32)          # (Gt, d)
-    rows = rows_ref[...].astype(jnp.float32)    # (Gt, L, d)
-    dots = jnp.sum(rows * h[:, None, :], axis=-1)  # (Gt, L)
-    out_ref[...] = alpha * dots * dots + 1.0
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("alpha", "g_tile", "interpret"))
-def midx_member_scores(h: Array, rows: Array, *, alpha: float = 100.0,
-                       g_tile: int = 128, interpret: bool = False) -> Array:
-    """h: (G, d); rows: (G, L, d) gathered posting lists -> (G, L) fp32
-    exact within-list kernel scores.  G must divide by g_tile."""
-    g, d = h.shape
-    leaf = rows.shape[1]
-    assert g % g_tile == 0, (g, g_tile)
-    kernel = functools.partial(_member_scores_kernel, alpha)
-    return pl.pallas_call(
-        kernel,
-        grid=(g // g_tile,),
-        in_specs=[
-            pl.BlockSpec((g_tile, d), lambda i: (i, 0)),
-            pl.BlockSpec((g_tile, leaf, d), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((g_tile, leaf), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, leaf), jnp.float32),
-        interpret=interpret,
-    )(h, rows)
+    )(h, ct, cnt.reshape(1, p))

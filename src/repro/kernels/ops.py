@@ -19,7 +19,6 @@ from repro.kernels.fused_head import MASK_CORR
 from repro.kernels.fused_head import fused_lse as _fused_lse
 from repro.kernels.fused_head import fused_lse_bwd as _fused_lse_bwd
 from repro.kernels.leaf_scores import leaf_scores as _leaf_scores
-from repro.kernels.midx_scores import midx_member_scores as _midx_member
 from repro.kernels.midx_scores import midx_pair_masses as _midx_pair
 from repro.kernels import ref
 from repro.kernels.rff_features import rff_features as _rff_features
@@ -33,6 +32,12 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+#: VMEM bytes one pipelined tile may take.  Pallas double-buffers every
+#: block and the kernel bodies hold fp32 temporaries of about a tile, so
+#: this keeps each kernel well inside the 16 MiB default scoped VMEM.
+TILE_VMEM_BYTES = 2 * 1024 * 1024
+
+
 def _pad_to(x: Array, axis: int, mult: int):
     n = x.shape[axis]
     pad = (-n) % mult
@@ -43,6 +48,25 @@ def _pad_to(x: Array, axis: int, mult: int):
     return jnp.pad(x, widths), n
 
 
+def _sublane_tile(n: int, cap: int = 128) -> int:
+    """Power-of-two tile of at least 8 rows for a second-minor block dim."""
+    return min(cap, max(8, 1 << (n - 1).bit_length()))
+
+
+def _divisor_tile(n: int, unit_bytes: int, align: int = 128) -> int:
+    """Tile of an axis of length n whose every index costs ``unit_bytes`` of
+    VMEM: the whole axis when it fits ``TILE_VMEM_BYTES``, else the largest
+    multiple of ``align`` that divides n and fits (n itself if none does)."""
+    if n * unit_bytes <= TILE_VMEM_BYTES:
+        return n
+    t = TILE_VMEM_BYTES // unit_bytes // align * align
+    while t >= align:
+        if n % t == 0:
+            return t
+        t -= align
+    return n
+
+
 def zstats(w: Array) -> Array:
     """w: (n_blocks, B, r) -> (n_blocks, r, r) fp32 block Grams."""
     return _zstats(w, interpret=_interpret())
@@ -51,8 +75,13 @@ def zstats(w: Array) -> Array:
 def block_scores(h: Array, z: Array, cnt: Array,
                  alpha: float = 100.0) -> Array:
     """h: (T, r); z: (N, r, r); cnt: (N,) -> (T, N) kernel masses."""
-    t_tile = min(128, max(8, 1 << (h.shape[0] - 1).bit_length()))
-    n_tile = min(8, z.shape[0])
+    r = z.shape[-1]
+    t_tile = _sublane_tile(h.shape[0])
+    # (Nt, r, r) stats tile; Nt is the output's lane dim: all of N, or a
+    # multiple of 128.
+    n_tile = z.shape[0]
+    if n_tile * r * r * 4 > TILE_VMEM_BYTES:
+        n_tile = max(128, TILE_VMEM_BYTES // (r * r * 4) // 128 * 128)
     hp, t = _pad_to(h, 0, t_tile)
     zp, n = _pad_to(z, 0, n_tile)
     cp, _ = _pad_to(cnt, 0, n_tile)
@@ -62,27 +91,30 @@ def block_scores(h: Array, z: Array, cnt: Array,
     return out[:t, :n]
 
 
-def _leaf_call(h: Array, rows: Array, *, alpha: float, square: bool) -> Array:
-    g_tile = min(128, max(8, 1 << (h.shape[0] - 1).bit_length()))
-    hp, g = _pad_to(h, 0, g_tile)
-    rp, _ = _pad_to(rows, 0, g_tile)
-    out = _leaf_scores(hp, rp, alpha=alpha, square=square,
-                       g_tile=min(g_tile, hp.shape[0]),
-                       interpret=_interpret())
-    return out[:g]
+def _leaf_call(h: Array, table: Array, idx: Array, *, alpha: float,
+               square: bool) -> Array:
+    _, b, r = table.shape
+    # per leaf row: its fp32 tile row plus one column of the resident
+    # (m, Bt) output block
+    b_tile = _divisor_tile(b, 4 * (r + idx.shape[1]))
+    return _leaf_scores(h, table, idx, alpha=alpha, square=square,
+                        b_tile=b_tile, interpret=_interpret())
 
 
-def leaf_scores(h: Array, rows: Array, alpha: float = 100.0) -> Array:
-    """h: (G, r); rows: (G, B, r) -> (G, B) quadratic-kernel scores."""
-    return _leaf_call(h, rows, alpha=alpha, square=True)
+def leaf_scores(h: Array, table: Array, idx: Array,
+                alpha: float = 100.0) -> Array:
+    """h: (T, r); table: (L, B, r); idx: (T, m) leaf ids -> (T, m, B)
+    quadratic-kernel scores of every row of each query's drawn leaves."""
+    return _leaf_call(h, table, idx, alpha=alpha, square=True)
 
 
-def leaf_dots(h: Array, rows: Array) -> Array:
-    """h: (G, r); rows: (G, B, r) -> (G, B) raw dots <h_g, w_{g,b}>.
+def leaf_dots(h: Array, table: Array, idx: Array) -> Array:
+    """h: (T, r); table: (L, B, r); idx: (T, m) -> (T, m, B) raw dots
+    <h_t, w_{idx[t, j], b}>.
 
     The exact-scoring step of serving-side beam retrieval: same kernel and
     VMEM schedule as ``leaf_scores``, without the kernelization."""
-    return _leaf_call(h, rows, alpha=0.0, square=False)
+    return _leaf_call(h, table, idx, alpha=0.0, square=False)
 
 
 def midx_list_masses(h: Array, c1: Array, c2: Array, codes: Array,
@@ -95,7 +127,7 @@ def midx_list_masses(h: Array, c1: Array, c2: Array, codes: Array,
     count multiply.  Padded lists get cnt 0, hence mass exactly 0."""
     ct = (c1.astype(jnp.float32)[codes[:, 0]]
           + c2.astype(jnp.float32)[codes[:, 1]])
-    t_tile = min(128, max(8, 1 << (h.shape[0] - 1).bit_length()))
+    t_tile = _sublane_tile(h.shape[0])
     p_tile = min(128, max(8, 1 << (ct.shape[0] - 1).bit_length()))
     hp, t = _pad_to(h, 0, t_tile)
     ctp, p = _pad_to(ct, 0, p_tile)
@@ -107,18 +139,6 @@ def midx_list_masses(h: Array, c1: Array, c2: Array, codes: Array,
     return out[:t, :p]
 
 
-def midx_member_scores(h: Array, rows: Array, alpha: float = 100.0) -> Array:
-    """h: (G, d); rows: (G, L, d) gathered posting lists -> (G, L) fp32
-    exact within-list quadratic-kernel scores (DESIGN.md §2.9)."""
-    g_tile = min(128, max(8, 1 << (h.shape[0] - 1).bit_length()))
-    hp, g = _pad_to(h, 0, g_tile)
-    rp, _ = _pad_to(rows, 0, g_tile)
-    out = _midx_member(hp, rp, alpha=alpha,
-                       g_tile=min(g_tile, hp.shape[0]),
-                       interpret=_interpret())
-    return out[:g]
-
-
 def rff_features(w: Array, omega: Array, mask: Array, logshift: Array, *,
                  tau: float = 1.0) -> Array:
     """w: (L, B, d); omega: (D, d); mask: (L, B); logshift: () traced scalar
@@ -128,17 +148,19 @@ def rff_features(w: Array, omega: Array, mask: Array, logshift: Array, *,
     feature matrix never hits HBM.  Padded feature columns (zero omega rows)
     produce junk that is sliced off; padded leaf rows are masked to zero."""
     n_feat = omega.shape[0]
-    l_tile = min(8, max(1, 1 << (w.shape[0] - 1).bit_length()))
+    d = w.shape[-1]
     d_tile = min(128, max(8, 1 << (n_feat - 1).bit_length()))
-    wp, n_leaves = _pad_to(w, 0, l_tile)
-    mp, _ = _pad_to(mask, 0, l_tile)
+    # leaf rows are the second-minor dim of the flat (L*B, d) class view
+    wp, _ = _pad_to(w, 1, 8)
+    mp, _ = _pad_to(mask, 1, 8)
     op, _ = _pad_to(omega, 0, d_tile)
+    # per class row: its fp32 tile row, the w*w temporary, one feature row
+    b_tile = _divisor_tile(wp.shape[1], 4 * (2 * d + d_tile), align=8)
     out = _rff_features(wp, op, mp, jnp.reshape(logshift, (1, 1)),
-                        tau=tau, d_total=n_feat,
-                        l_tile=min(l_tile, wp.shape[0]),
+                        tau=tau, d_total=n_feat, b_tile=b_tile,
                         d_tile=min(d_tile, op.shape[0]),
                         interpret=_interpret())
-    return out[:n_leaves, :n_feat]
+    return out[:, :n_feat]
 
 
 def sampled_loss(h: Array, w_neg: Array, logq: Array, pos_logit: Array,
@@ -165,19 +187,30 @@ def sampled_loss(h: Array, w_neg: Array, logq: Array, pos_logit: Array,
 #: token-chunk size of the non-TPU fallback: peak gather is (chunk, K, d).
 FUSED_HEAD_CHUNK = 128
 #: VMEM budget for the Pallas backward's resident (n, d) dL/dw accumulator;
-#: larger head shards fall back to the chunked path.
+#: larger head shards take the chunked path.
 FUSED_HEAD_VMEM_BYTES = 8 * 1024 * 1024
 
 
-def _resolve_fused_impl(impl: str, n: int, d: int) -> str:
+def resolve_fused_impl(impl: str, n: int, d: int) -> str:
+    """The path ``fused_head_lse`` takes for an (n, d) head table.
+
+    "auto" is the Pallas kernel on TPU when the (n, d) fp32 dL/dw
+    accumulator fits ``FUSED_HEAD_VMEM_BYTES``, else the chunked jnp path.
+    An explicit "pallas" whose accumulator does not fit is refused here:
+    the TPU compiler would refuse it anyway."""
     if impl not in ("auto", "pallas", "chunked"):
         raise ValueError(f"fused_head_lse impl={impl!r} not in "
                          "('auto', 'pallas', 'chunked')")
+    fits = n * d * 4 <= FUSED_HEAD_VMEM_BYTES
+    if impl == "pallas" and not fits:
+        raise ValueError(
+            f"fused_head_lse impl='pallas' needs the ({n}, {d}) fp32 dL/dw "
+            f"accumulator in VMEM ({n * d * 4} bytes > "
+            f"FUSED_HEAD_VMEM_BYTES={FUSED_HEAD_VMEM_BYTES}); use 'auto' "
+            "or 'chunked'")
     if impl != "auto":
         return impl
-    if not _interpret() and n * d * 4 <= FUSED_HEAD_VMEM_BYTES:
-        return "pallas"
-    return "chunked"
+    return "pallas" if fits and not _interpret() else "chunked"
 
 
 def _fused_chunks(t: int, *arrays):
@@ -288,7 +321,7 @@ def fused_head_lse(w: Array, h: Array, ids: Array, corr: Array,
     t, k = ids.shape
     if biasg is None:
         biasg = jnp.zeros((t, k), jnp.float32)
-    impl = _resolve_fused_impl(impl, *w.shape)
+    impl = resolve_fused_impl(impl, *w.shape)
     return _fused_head_lse(w, h, ids.astype(jnp.int32),
                            corr.astype(jnp.float32),
                            biasg.astype(jnp.float32), bool(abs_mode), impl)

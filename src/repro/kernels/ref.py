@@ -21,17 +21,18 @@ def block_scores_ref(h: Array, z: Array, cnt: Array, alpha: float) -> Array:
     return alpha * quad + cnt[None, :]
 
 
-def leaf_scores_ref(h: Array, rows: Array, alpha: float) -> Array:
-    """h: (G, r); rows: (G, B, r) -> (G, B) quadratic-kernel scores."""
-    dots = jnp.einsum("gbr,gr->gb", rows.astype(jnp.float32),
+def leaf_dots_ref(h: Array, table: Array, idx: Array) -> Array:
+    """h: (T, r); table: (L, B, r); idx: (T, m) -> (T, m, B) raw dot
+    products (logits) of every row of each query's drawn leaves."""
+    return jnp.einsum("tmbr,tr->tmb", table[idx].astype(jnp.float32),
                       h.astype(jnp.float32))
-    return alpha * jnp.square(dots) + 1.0
 
 
-def leaf_dots_ref(h: Array, rows: Array) -> Array:
-    """h: (G, r); rows: (G, B, r) -> (G, B) raw dot products (logits)."""
-    return jnp.einsum("gbr,gr->gb", rows.astype(jnp.float32),
-                      h.astype(jnp.float32))
+def leaf_scores_ref(h: Array, table: Array, idx: Array,
+                    alpha: float) -> Array:
+    """h: (T, r); table: (L, B, r); idx: (T, m) -> (T, m, B)
+    quadratic-kernel scores alpha * dot^2 + 1."""
+    return alpha * jnp.square(leaf_dots_ref(h, table, idx)) + 1.0
 
 
 def midx_list_masses_ref(h: Array, c1: Array, c2: Array, codes: Array,
@@ -44,14 +45,6 @@ def midx_list_masses_ref(h: Array, c1: Array, c2: Array, codes: Array,
           + c2.astype(jnp.float32)[codes[:, 1]])          # (P, d)
     dots = h.astype(jnp.float32) @ ct.T                   # (T, P)
     return cnt[None, :] * (alpha * jnp.square(dots) + 1.0)
-
-
-def midx_member_scores_ref(h: Array, rows: Array, alpha: float) -> Array:
-    """h: (G, d); rows: (G, L, d) -> (G, L) exact within-list kernel
-    scores alpha * dot^2 + 1."""
-    dots = jnp.einsum("gld,gd->gl", rows.astype(jnp.float32),
-                      h.astype(jnp.float32))
-    return alpha * jnp.square(dots) + 1.0
 
 
 def rff_features_ref(w: Array, omega: Array, mask: Array, logshift,

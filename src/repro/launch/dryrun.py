@@ -83,20 +83,11 @@ def pick_optimizer(cfg, ctx):
     return make_optimizer(name, 1e-4), name, n
 
 
-def _cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict or a 1-elem list of dicts
-    depending on the jax version — normalize to a dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def analyze(lowered, compiled, mesh) -> dict:
     from repro.launch.hlo_analysis import analyze_hlo
 
     ma = compiled.memory_analysis()
-    ca = _cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     txt = compiled.as_text()
     corrected = analyze_hlo(txt)  # trip-count-aware (scan bodies x trips)
     return {
@@ -200,7 +191,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
              out_dir: str) -> dict:
     lowered, compiled, mesh, meta = lower_cell(arch, shape_name, multi_pod)
     print(compiled.memory_analysis())
-    ca = _cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
     rec = {**meta, **analyze(lowered, compiled, mesh)}
     os.makedirs(out_dir, exist_ok=True)

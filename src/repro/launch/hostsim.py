@@ -37,8 +37,9 @@ def ensure_host_platform_devices(n: int) -> None:
     """Guarantee jax sees exactly ``n`` host-platform devices, or fail loudly.
 
       * backend not yet initialized — merge the flag into ``XLA_FLAGS``
-        (preserving unrelated flags, replacing any previous count) and
-        verify by initializing;
+        (preserving unrelated flags, replacing any previous count), pin
+        the cpu platform (``JAX_PLATFORMS=cpu``, inherited by children),
+        and verify by initializing;
       * backend already initialized with ``n`` devices — no-op, so a gate
         can run twice in one process (e.g. two tests in one pytest run);
       * backend initialized with any other count — pointed RuntimeError:
@@ -53,6 +54,16 @@ def ensure_host_platform_devices(n: int) -> None:
                  if not t.startswith(FLAG + "=")]
         flags.append(f"{FLAG}={n}")
         os.environ["XLA_FLAGS"] = " ".join(flags)
+        # Simulated hosts are CPU devices.  Pin the platform before the
+        # backend starts, so that neither this process nor a child that
+        # inherits its environment reaches for an attached accelerator.
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"host-platform simulation needs the cpu backend, but jax "
+            f"already started on {jax.default_backend()!r}; run it in a "
+            "fresh process")
     have = jax.device_count()  # initializes the backend on first call
     if have != n:
         raise RuntimeError(

@@ -19,7 +19,17 @@ from __future__ import annotations
 import os
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the partitioner propagates
+    shardings from the ``with_sharding_constraint`` annotations this repo
+    places (``ShardCtx.act``).  jax.make_mesh's own default is Explicit
+    axes, under which sharding is part of every type and an op whose
+    output sharding is ambiguous (a gather from a sharded table) fails."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_shape_for(devices: int, tp: int = 0) -> tuple[int, int]:
@@ -46,7 +56,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 single pod (256 chips) or 2x16x16 two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_mesh_for(devices: int, tp: int = 0) -> Mesh:
@@ -54,12 +64,12 @@ def make_mesh_for(devices: int, tp: int = 0) -> Mesh:
 
     tp=0 picks the largest power-of-two TP degree <= min(16, devices)."""
     dp, tp = mesh_shape_for(devices, tp)
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return _mesh((dp, tp), ("data", "model"))
 
 
 def make_debug_mesh(dp: int = 2, tp: int = 4) -> Mesh:
     """Small host-device mesh for tests (needs device_count >= dp*tp)."""
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return _mesh((dp, tp), ("data", "model"))
 
 
 # ---- multi-host ------------------------------------------------------------

@@ -24,7 +24,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models.layers import dense_init
 from repro.sharding.rules import ShardCtx
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 Params = dict
@@ -193,7 +192,7 @@ def apply_moe(p: Params, x: Array, cfg: ArchConfig, ctx: ShardCtx
                 aux = lax.pmean(aux, a)
             return y_loc.reshape(bl, s, d), aux
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             sharded, mesh=mesh, check_vma=False,
             in_specs=(P(dataspec, None, None), P(None, None), P(None),
                       P(ctx.model_axis, wdsp, None),

@@ -40,7 +40,6 @@ from repro.sharding.rules import (
     head_fd_axes,
     param_specs_for,
 )
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 
@@ -92,7 +91,7 @@ def decode_topk(cfg: ArchConfig, ctx: ShardCtx, head, h2d, k: int, *,
         return distributed.sharded_logits_topk(
             head_full, h_l, k, axis_name=mdl, bias_local=bias)
 
-    return shard_map(
+    return jax.shard_map(
         island, mesh=ctx.mesh, check_vma=False,
         in_specs=(P(mdl, head_fd_axes(ctx)), P(dataspec, None)),
         out_specs=(P(dataspec, None), P(dataspec, None)))(head, h2d)
@@ -131,12 +130,17 @@ def make_decode_fn(cfg: ArchConfig, ctx: ShardCtx, head, k: int, *,
     engine (``serve/server.py``): the index rides as a PYTREE ARGUMENT so
     the engine's double-buffered swap re-binds buffers without recompiling
     — only the microbatch bucket shapes (and the dense ``index=None``
-    treedef) ever compile.  ``index=None`` serves the dense head path."""
+    treedef) ever compile.  ``index=None`` serves the dense head path.
 
-    def decode(index, h2d):
-        return decode_topk(cfg, ctx, head, h2d, k, index=index, beam=beam)
+    The head table rides as a leaf of the returned ``jax.tree_util.Partial``
+    and the engine passes it through jit as an argument: closed over, the
+    (n, d) table would be baked into every compiled program as a constant
+    (hundreds of MB at LM vocabularies)."""
 
-    return decode
+    def decode(head_, index, h2d):
+        return decode_topk(cfg, ctx, head_, h2d, k, index=index, beam=beam)
+
+    return jax.tree_util.Partial(decode, head)
 
 
 def make_decode_step(cfg: ArchConfig, ctx: ShardCtx):

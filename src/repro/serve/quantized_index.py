@@ -40,7 +40,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import midx
 from repro.sharding.rules import gather_head_fd, head_fd_axes
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 
@@ -153,7 +152,7 @@ def build_quantized_index(w: Array, ctx=None, *, codewords: int = 16,
                             codebooks=codebooks, list_size=list_size,
                             bits=bits)
 
-    parts = shard_map(
+    parts = jax.shard_map(
         island, mesh=ctx.mesh, check_vma=False,
         in_specs=(P(mdl, head_fd_axes(ctx)),),
         out_specs=(P(mdl),) * 7)(w)
@@ -219,7 +218,7 @@ def decode_topk(index: QuantizedRetrievalIndex, h: Array, k: int,
         return (jnp.take_along_axis(all_ids, sel, axis=1).astype(jnp.int32),
                 logits)
 
-    return shard_map(
+    return jax.shard_map(
         island, mesh=ctx.mesh, check_vma=False,
         in_specs=(P(mdl),) * 7 + (P(dataspec, None),),
         out_specs=(P(dataspec, None), P(dataspec, None)))(

@@ -17,7 +17,8 @@ compression instead,
 
     h^T Z_C h  <=  sum_{i<s} lam_i <h, v_i>^2 + lam_res ||h||^2   [spec]
 
-(top-s eigenpairs of Z_C plus the next eigenvalue as a residual cap) —
+(top-s eigenpairs of Z_C plus the next eigenvalue as a residual cap; above
+r = ``SPECTRAL_SUBSPACE`` they come from a subspace iteration) —
 s*r flops per node, empirically within a few percent of the exact kernel
 bound's pruning quality.  All serving statistics are built once per index
 build on the same cadence as the Gram sums and carried heap-packed in the
@@ -65,6 +66,7 @@ sampling-side low-rank projection (DESIGN.md §2.3) does not apply here.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -77,7 +79,6 @@ from repro.core.midx import pc_bisect_perm  # noqa: F401  (canonical home
 # moved to core/midx.py — the midx posting lists and this serving index
 # share ONE balanced bisection; re-exported for existing callers)
 from repro.sharding.rules import gather_head_fd, head_fd_axes
-from repro.utils.compat import shard_map
 from repro.utils.misc import log2_int, next_pow2
 
 Array = jax.Array
@@ -167,30 +168,59 @@ def ball_stats(w_pad: Array, n_valid: Array | int, depth: int
     return tuple(mus), tuple(rads)
 
 
+#: subspace the spectral statistics are computed in, and its power
+#: iterations.  The TPU compiles a dense ``eigh`` above 256 x 256 into a
+#: program that takes minutes to compile (over six at r = 3072); up to
+#: this width the subspace is the whole space and the result exact.
+SPECTRAL_SUBSPACE, SPECTRAL_ITERS = 128, 16
+
+
+def _top_eigh(z: Array) -> tuple[Array, Array]:
+    """Top eigenpairs of a batch of symmetric PSD (N, r, r): values (N, p)
+    descending and vectors (N, p, r), p = min(r, SPECTRAL_SUBSPACE).
+
+    Block power iteration from a fixed start, then Rayleigh-Ritz: the
+    only ``eigh`` is p x p.  Exact when p == r (the start spans the whole
+    space); otherwise the Ritz values approach the top eigenvalues from
+    below at rate (lam_{p+1} / lam_i) ** SPECTRAL_ITERS."""
+    r = z.shape[-1]
+    p = min(r, SPECTRAL_SUBSPACE)
+    mm = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+    q = jnp.broadcast_to(
+        jax.random.normal(jax.random.PRNGKey(0), (r, p), z.dtype),
+        z.shape[:-1] + (p,))
+    q = jnp.linalg.qr(q)[0]
+    if p < r:
+        q = lax.fori_loop(0, SPECTRAL_ITERS,
+                          lambda _, q_: jnp.linalg.qr(mm(z, q_))[0], q)
+    vals, u = jnp.linalg.eigh(mm(jnp.swapaxes(q, -1, -2), mm(z, q)))
+    vecs = jnp.swapaxes(mm(q, u), -1, -2)             # (N, p, r) ascending
+    return vals[..., ::-1], vecs[..., ::-1, :]
+
+
 def spectral_stats(levels_z, s: int = 4
                    ) -> tuple[tuple[Array, ...], tuple[Array, ...]]:
     """Rank-s spectral compression of every node's Gram sum.
 
     For each node, the top-s eigenpairs of Z_C plus a residual cap give the
-    sound quadratic-form bound h^T Z_C h <= sum lam_i <h,v_i>^2 +
-    lam_res ||h||^2 at s*r flops per node (vs r^2 for the exact form).
-    Returns (levels_evecs of (nodes, s, r), levels_evals of (nodes, s+1))
-    with evals[..., s] the residual cap (0 when s >= r).  One batched
-    ``eigh`` per level — build-time only."""
+    quadratic-form bound h^T Z_C h <= sum lam_i <h,v_i>^2 + lam_res ||h||^2
+    at s*r flops per node (vs r^2 for the exact form).  Returns
+    (levels_evecs of (nodes, s, r), levels_evals of (nodes, s+1)) with
+    evals[..., s] the residual cap (0 when s >= r).  Up to
+    ``SPECTRAL_SUBSPACE`` wide the eigenpairs are exact and the bound is
+    sound; wider, they come from ``_top_eigh``'s subspace and the cap can
+    fall short of lam_{s+1} — the bound then only ranks the beam, and a
+    full-beam decode stays exact whatever it says.  Build-time only."""
     r = levels_z[0].shape[-1]
     s = min(s, r)
     evecs_lvls, evals_lvls = [], []
     for z in levels_z:
-        vals, vecs = jnp.linalg.eigh(z)  # ascending
-        top_vals = vals[..., ::-1][..., :s]
-        top_vecs = jnp.moveaxis(vecs[..., ::-1][..., :s], -1, -2)  # (n, s, r)
-        if s == r:
-            res = jnp.zeros(vals.shape[:-1], vals.dtype)
-        else:
-            res = vals[..., r - s - 1]
-        evecs_lvls.append(top_vecs)
+        vals, vecs = _top_eigh(z)
+        res = (vals[..., s] if s < r
+               else jnp.zeros(vals.shape[:-1], vals.dtype))
+        evecs_lvls.append(vecs[..., :s, :])
         evals_lvls.append(
-            jnp.concatenate([top_vals, res[..., None]], axis=-1))
+            jnp.concatenate([vals[..., :s], res[..., None]], axis=-1))
     return tuple(evecs_lvls), tuple(evals_lvls)
 
 
@@ -257,7 +287,7 @@ def build_index(w: Array, ctx=None, *, leaf_size: int | None = None,
         n_valid = jnp.clip(n - my * v_l, 0, v_l)
         return _build_local(w_full, leaf, n_valid, cluster)
 
-    z, cnt, wq, mu, rad, evc, evl, perm = shard_map(
+    z, cnt, wq, mu, rad, evc, evl, perm = jax.shard_map(
         island, mesh=ctx.mesh, check_vma=False,
         in_specs=(P(mdl, head_fd_axes(ctx)),),
         out_specs=(P(mdl),) * 8)(w)
@@ -394,7 +424,8 @@ def leaf_topk(stats: HierarchyStats, h: Array, leaves: Array, k: int, *,
     h: (T, r); leaves: (T, B) leaf indices -> ids (T, k) int32 local class
     ids and logits (T, k) fp32 exact dots, sorted descending.  Padding rows
     (local id >= n_valid) score -inf.  The B * leaf_size gathered rows are
-    scored by the ``leaf_scores`` kernel in dot mode when ``use_kernels``.
+    scored by the ``leaf_scores`` kernel in dot mode when ``use_kernels``,
+    which fetches each surviving leaf by block index (no gathered copy).
     """
     if use_kernels is None:
         use_kernels = jax.default_backend() == "tpu"
@@ -403,13 +434,11 @@ def leaf_topk(stats: HierarchyStats, h: Array, leaves: Array, k: int, *,
     leaf = stats.leaf_size
     assert k <= b * leaf, (
         f"k={k} needs beam*leaf_size >= k, got {b}*{leaf}")
-    rows = stats.wq[leaves]  # (T, B, leaf, r)
     if use_kernels:
         from repro.kernels import ops
-        flat_rows = rows.reshape(t * b, leaf, -1)
-        flat_h = jnp.repeat(hq, b, axis=0)
-        dots = ops.leaf_dots(flat_h, flat_rows).reshape(t, b, leaf)
+        dots = ops.leaf_dots(hq, stats.wq, leaves)
     else:
+        rows = stats.wq[leaves]  # (T, B, leaf, r)
         dots = jnp.einsum("tblr,tr->tbl", rows, hq)
     ids = leaves[..., None] * leaf + jnp.arange(leaf)  # (T, B, leaf)
     dots = jnp.where(ids < stats.n_valid, dots, -jnp.inf)
@@ -492,7 +521,7 @@ def decode_topk(index: RetrievalIndex, h: Array, k: int,
         logits, sel = lax.top_k(all_logits, k)
         return jnp.take_along_axis(all_ids, sel, axis=1), logits
 
-    return shard_map(
+    return jax.shard_map(
         island, mesh=ctx.mesh, check_vma=False,
         in_specs=(P(mdl),) * 8 + (P(dataspec, None),),
         out_specs=(P(dataspec, None), P(dataspec, None)))(

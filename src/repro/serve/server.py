@@ -120,10 +120,11 @@ class LatencyHistogram:
 
 @dataclasses.dataclass
 class ServeResult:
-    """One request's answer.  ``ok=False`` => deadline expiry or engine
-    shutdown; ``index_version`` is the version of the index snapshot that
-    served the WHOLE request (cache hits report the version they were
-    cached under, which by the version-scoped key IS the current one)."""
+    """One request's answer.  ``ok=False`` => deadline expiry, engine
+    shutdown, or a failed decode (``error`` names it); ``index_version`` is
+    the version of the index snapshot that served the WHOLE request (cache
+    hits report the version they were cached under, which by the
+    version-scoped key IS the current one)."""
 
     ids: np.ndarray | None
     logits: np.ndarray | None
@@ -203,7 +204,9 @@ class ServingEngine:
     decode_fn: ``(index, h (B, d)) -> (ids (B, k), logits (B, k))`` —
         jit-compatible; compiled here once per bucket shape (and per index
         treedef: the dense path's ``index=None`` and the retrieval path
-        coexist).  ``engine.make_decode_fn`` builds the standard one.
+        coexist).  ``engine.make_decode_fn`` builds the standard one, a
+        ``jax.tree_util.Partial`` whose leaves (the head table) enter the
+        compiled program as arguments.
     d_model: hidden-state width every request must match.
     k: returned candidates per request (informational; decode_fn owns it).
     buckets: ascending microbatch shapes to pad into — the complete set of
@@ -237,7 +240,13 @@ class ServingEngine:
         self.buckets = tuple(int(b) for b in buckets)
         self.max_wait_s = max_wait_ms / 1e3
         self.default_deadline_s = default_deadline_ms / 1e3
-        self._decode = jax.jit(decode_fn)
+        # The decode callable goes through jit as an ARGUMENT: a
+        # ``jax.tree_util.Partial`` (``engine.make_decode_fn``) carries its
+        # arrays — the head table — as inputs of the compiled program
+        # instead of constants baked into it.
+        self._fn = (decode_fn if isinstance(decode_fn, jax.tree_util.Partial)
+                    else jax.tree_util.Partial(decode_fn))
+        self._decode = jax.jit(lambda fn, index, h: fn(index, h))
         self._cache = _HotCache(cache_size, cache_quant) if cache_size \
             else None
 
@@ -245,6 +254,8 @@ class ServingEngine:
         self._queue: deque[_Request] = deque()
         self._running = False
         self._thread: threading.Thread | None = None
+        # the exception that killed the worker; stop() re-raises it
+        self._error: Exception | None = None
         # the double buffer: ONE reference, swapped atomically, read once
         # per microbatch.  (index, version, train_step_it_was_built_from)
         self._index_ref: tuple[Any, int, int] = (
@@ -257,7 +268,7 @@ class ServingEngine:
 
         self._hist = LatencyHistogram()
         self._c = {
-            "submitted": 0, "completed": 0, "expired": 0, "rejected": 0,
+            "submitted": 0, "completed": 0, "expired": 0, "failed": 0,
             "cache_hits": 0, "cache_misses": 0,
             "microbatches": 0, "batch_slots": 0, "batch_real": 0,
             "queue_depth_peak": 0, "index_swaps": 0,
@@ -271,7 +282,7 @@ class ServingEngine:
             index, _, _ = self._index_ref
             for b in self.buckets:
                 z = np.zeros((b, self.d_model), np.float32)
-                jax.block_until_ready(self._decode(index, z))
+                jax.block_until_ready(self._decode(self._fn, index, z))
         with self._lock:
             if self._running:
                 return self
@@ -282,7 +293,10 @@ class ServingEngine:
         return self
 
     def stop(self) -> None:
-        """Drain: in-queue requests are failed with 'engine stopped'."""
+        """Drain: in-queue requests are failed with 'engine stopped'.
+
+        Re-raises (as the cause of a RuntimeError) the exception that
+        killed the worker, if a decode failed."""
         with self._lock:
             self._running = False
             pending = list(self._queue)
@@ -294,6 +308,8 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join()
             self._thread = None
+        if self._error is not None:
+            raise RuntimeError("serving worker died") from self._error
 
     # -- request side --------------------------------------------------------
     def submit(self, h: np.ndarray,
@@ -309,6 +325,13 @@ class ServingEngine:
         req = _Request(h, time.perf_counter() + ddl_s)
         with self._lock:
             self._c["submitted"] += 1
+            if self._error is not None:
+                # the worker is dead: fail now instead of queueing forever
+                self._c["failed"] += 1
+                req._finish(ServeResult(None, None, False,
+                                        _decode_error(self._error), -1,
+                                        False, 0.0))
+                return req
             self._queue.append(req)
             self._c["queue_depth_peak"] = max(self._c["queue_depth_peak"],
                                               len(self._queue))
@@ -435,9 +458,13 @@ class ServingEngine:
             h_pad = np.zeros((bucket, self.d_model), np.float32)
             for i, r in enumerate(misses):
                 h_pad[i] = r.h
-            ids, logits = self._decode(index, h_pad)
-            ids = np.asarray(ids)
-            logits = np.asarray(logits)
+            try:
+                ids, logits = self._decode(self._fn, index, h_pad)
+                ids = np.asarray(ids)
+                logits = np.asarray(logits)
+            except Exception as e:  # noqa: BLE001
+                self._die(misses, e)
+                return
             with self._lock:
                 self._c["microbatches"] += 1
                 self._c["batch_slots"] += bucket
@@ -452,6 +479,25 @@ class ServingEngine:
                     self._hist.record(ms)
                 r._finish(ServeResult(ids[i], logits[i], True, None,
                                       version, False, ms))
+
+    def _die(self, in_flight: list[_Request], err: Exception) -> None:
+        """A decode raised: fail the in-flight and every queued request
+        with the error, and stop taking work.  No future is left waiting;
+        ``stop()`` re-raises ``err``."""
+        with self._lock:
+            self._error = err
+            self._running = False
+            doomed = in_flight + list(self._queue)
+            self._queue.clear()
+            self._c["failed"] += len(doomed)
+            self._lock.notify_all()
+        for r in doomed:
+            r._finish(ServeResult(None, None, False, _decode_error(err), -1,
+                                  False, _ms_since(r.t_enq)))
+
+
+def _decode_error(err: Exception) -> str:
+    return f"decode failed: {type(err).__name__}: {err}"
 
 
 def _ms_since(t0: float) -> float:

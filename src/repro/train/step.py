@@ -47,7 +47,6 @@ from repro.models import api
 from repro.models.transformer import padded_vocab
 from repro.optim.transform import GradientTransform, apply_updates
 from repro.sharding.rules import ShardCtx, param_specs_for
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 
@@ -125,7 +124,7 @@ def make_refresh_fn(cfg: ArchConfig, ctx: ShardCtx
             n_valid = jnp.asarray(cfg.vocab_size, jnp.int32)
             stats = sampler.build_stats(head, n_valid, sampler_state.const)
         else:
-            stats = shard_map(
+            stats = jax.shard_map(
                 island, mesh=mesh, check_vma=False,
                 in_specs=(P(mdl, head_fsdp), specs.const),
                 out_specs=specs.stats,
@@ -140,26 +139,23 @@ def make_refresh_fn(cfg: ArchConfig, ctx: ShardCtx
     return refresh_fn
 
 
-def make_train_step(cfg: ArchConfig, ctx: ShardCtx, opt: GradientTransform,
-                    aux_coef: float = 0.01
-                    ) -> Callable[[TrainState, dict, Array],
-                                  tuple[TrainState, dict]]:
-    cfg.validate(tp=ctx.tp)
+def _make_head_loss(cfg: ArchConfig, ctx: ShardCtx
+                    ) -> Callable[[Array, Array, Array, SamplerState, Array],
+                                  Array]:
+    """``head_loss(head, h2d, labels, sampler_state, key)`` -> the GLOBAL
+    estimator loss SUM over all tokens: the head island of the train step
+    (a ``shard_map`` over the full mesh, or the mesh=None local path)."""
     sampler = sampler_from_config(cfg)
     estimator = estimators.make_estimator(cfg.estimator)
     mesh = ctx.mesh
     tp = ctx.tp
     m = cfg.m_negatives
-    dataspec = ctx.batch_spec() if ctx.mesh is not None else None
-    head_fsdp = (ctx.data_spec() if ctx.mesh is not None else None)
+    dataspec = ctx.batch_spec() if mesh is not None else None
+    head_fsdp = ctx.data_spec() if mesh is not None else None
     pure_fsdp = ctx.mode == "pure_fsdp"
     v_l = padded_vocab(cfg, tp) // tp  # head rows per vocab shard
-
-    carries_stats = sampler.carries_state and estimator.needs_sampling
     mdl = ctx.model_axis
-    # Specs must mirror the init gating: a dense estimator (estimator.
-    # needs_sampling False) carries an EMPTY state even for a carrying
-    # sampler, and the shard_map in_specs must match that empty pytree.
+    carries_stats = sampler.carries_state and estimator.needs_sampling
     specs = (sampler.state_specs(cfg, tp, axis=mdl) if carries_stats
              else empty_state())
 
@@ -171,35 +167,6 @@ def make_train_step(cfg: ArchConfig, ctx: ShardCtx, opt: GradientTransform,
         return sampler.island_runtime(sampler_state,
                                       lax.stop_gradient(head_full), n_valid)
 
-    # --- stats refresh (no gradients; runs once per step, before the
-    # microbatch loop, so all microbatches sample from the SAME q) ----------
-    def refresh_island(head, stats, const, refresh):
-        my = lax.axis_index(mdl)
-        head_full = head  # gather the Fd-sharded feature dim
-        for a in ctx.data_axes[::-1]:
-            head_full = lax.all_gather(head_full, a, axis=1, tiled=True)
-        n_valid = jnp.clip(cfg.vocab_size - my * v_l, 0, v_l)
-        new = sampler.build_stats(head_full, n_valid, const)
-        return _merge_refresh(new, stats, refresh)
-
-    def refresh_state(head, sampler_state: SamplerState, refresh
-                      ) -> SamplerState:
-        if not carries_stats:
-            return sampler_state
-        head = lax.stop_gradient(head)
-        if mesh is None:
-            n_valid = jnp.asarray(cfg.vocab_size, jnp.int32)
-            new = sampler.build_stats(head, n_valid, sampler_state.const)
-            return sampler_state.replace_stats(
-                _merge_refresh(new, sampler_state.stats, refresh))
-        stats = shard_map(
-            refresh_island, mesh=mesh, check_vma=False,
-            in_specs=(P(mdl, head_fsdp), specs.stats, specs.const, P()),
-            out_specs=specs.stats,
-        )(head, sampler_state.stats, sampler_state.const, refresh)
-        return sampler_state.replace_stats(stats)
-
-    # --- loss (differentiable; consumes fixed stats) ------------------------
     def head_island(head, h2d, labels, stats, const, key):
         """Runs per-(data,model) shard.  head: (v_l, d_l) local;
         h2d: (T_l, d); labels: (T_l,).  Returns the GLOBAL loss sum (scalar,
@@ -242,12 +209,88 @@ def make_train_step(cfg: ArchConfig, ctx: ShardCtx, opt: GradientTransform,
                 estimator, sampler, head, h2d, labels, sampler_state, m,
                 key, n_valid=jnp.asarray(cfg.vocab_size, jnp.int32),
                 abs_mode=cfg.abs_softmax, impl=cfg.head_impl))
-        return shard_map(
+        return jax.shard_map(
             head_island, mesh=mesh, check_vma=False,
             in_specs=(P(mdl, head_fsdp), P(dataspec, None), P(dataspec),
                       specs.stats, specs.const, P()),
             out_specs=P(),
         )(head, h2d, labels, sampler_state.stats, sampler_state.const, key)
+
+    return island_caller
+
+
+def make_eval_fn(cfg: ArchConfig, ctx: ShardCtx
+                 ) -> Callable[[Any, dict], Array]:
+    """``eval_fn(params, batch)`` -> mean full-softmax (eq. 1) loss.
+
+    The exact loss the sampled estimators approximate, through the same
+    backbone and head island as the train step (vocab-sharded on a mesh:
+    per-shard logsumexp, combined across the model axis — no (T, n) logit
+    tensor is gathered)."""
+    cfg = dataclasses.replace(cfg, estimator="full")
+    cfg.validate(tp=ctx.tp)
+    head_loss = _make_head_loss(cfg, ctx)
+
+    def eval_fn(params, batch):
+        h2d, labels, _ = api.backbone_hidden(params, batch, cfg, ctx)
+        head = api.head_table(params, cfg)
+        lsum = head_loss(head, h2d, labels, empty_state(),
+                         jax.random.PRNGKey(0))
+        return lsum / h2d.shape[0]
+
+    return eval_fn
+
+
+def make_train_step(cfg: ArchConfig, ctx: ShardCtx, opt: GradientTransform,
+                    aux_coef: float = 0.01
+                    ) -> Callable[[TrainState, dict, Array],
+                                  tuple[TrainState, dict]]:
+    cfg.validate(tp=ctx.tp)
+    sampler = sampler_from_config(cfg)
+    estimator = estimators.make_estimator(cfg.estimator)
+    mesh = ctx.mesh
+    tp = ctx.tp
+    head_fsdp = (ctx.data_spec() if ctx.mesh is not None else None)
+    v_l = padded_vocab(cfg, tp) // tp  # head rows per vocab shard
+
+    carries_stats = sampler.carries_state and estimator.needs_sampling
+    mdl = ctx.model_axis
+    # Specs must mirror the init gating: a dense estimator (estimator.
+    # needs_sampling False) carries an EMPTY state even for a carrying
+    # sampler, and the shard_map in_specs must match that empty pytree.
+    specs = (sampler.state_specs(cfg, tp, axis=mdl) if carries_stats
+             else empty_state())
+
+    # --- stats refresh (no gradients; runs once per step, before the
+    # microbatch loop, so all microbatches sample from the SAME q) ----------
+    def refresh_island(head, stats, const, refresh):
+        my = lax.axis_index(mdl)
+        head_full = head  # gather the Fd-sharded feature dim
+        for a in ctx.data_axes[::-1]:
+            head_full = lax.all_gather(head_full, a, axis=1, tiled=True)
+        n_valid = jnp.clip(cfg.vocab_size - my * v_l, 0, v_l)
+        new = sampler.build_stats(head_full, n_valid, const)
+        return _merge_refresh(new, stats, refresh)
+
+    def refresh_state(head, sampler_state: SamplerState, refresh
+                      ) -> SamplerState:
+        if not carries_stats:
+            return sampler_state
+        head = lax.stop_gradient(head)
+        if mesh is None:
+            n_valid = jnp.asarray(cfg.vocab_size, jnp.int32)
+            new = sampler.build_stats(head, n_valid, sampler_state.const)
+            return sampler_state.replace_stats(
+                _merge_refresh(new, sampler_state.stats, refresh))
+        stats = jax.shard_map(
+            refresh_island, mesh=mesh, check_vma=False,
+            in_specs=(P(mdl, head_fsdp), specs.stats, specs.const, P()),
+            out_specs=specs.stats,
+        )(head, sampler_state.stats, sampler_state.const, refresh)
+        return sampler_state.replace_stats(stats)
+
+    # --- loss (differentiable; consumes fixed stats) ------------------------
+    island_caller = _make_head_loss(cfg, ctx)
 
     def loss_fn(params, mb, sampler_state, key):
         h2d, labels, aux = api.backbone_hidden(params, mb, cfg, ctx)
@@ -429,8 +472,25 @@ def init_train_state(key, cfg: ArchConfig, ctx: ShardCtx,
                      opt: GradientTransform, max_len: int = 4096
                      ) -> TrainState:
     """Concrete (allocating) init — smoke tests / examples.  The dry-run uses
-    abstract_train_state instead."""
+    abstract_train_state instead.
+
+    On a mesh the state is built straight into the shardings
+    ``abstract_train_state`` declares (params and optimizer state sharded
+    by ``param_specs_for``, sampler statistics P('model')), so no device
+    ever holds the whole state."""
     cfg.validate(tp=ctx.tp)
+    if ctx.mesh is None:
+        return _init_train_state(key, cfg, ctx, opt, max_len)
+    shardings = jax.tree_util.tree_map(
+        lambda s: s.sharding, abstract_train_state(cfg, ctx, opt, max_len))
+    init = jax.jit(
+        lambda k: _init_train_state(k, cfg, ctx, opt, max_len),
+        out_shardings=shardings)
+    return init(key)
+
+
+def _init_train_state(key, cfg: ArchConfig, ctx: ShardCtx,
+                      opt: GradientTransform, max_len: int) -> TrainState:
     sampler = sampler_from_config(cfg)
     estimator = estimators.make_estimator(cfg.estimator)
     params = api.init_params(key, cfg, ctx, max_len=max_len)

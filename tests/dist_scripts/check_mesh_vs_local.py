@@ -19,7 +19,6 @@ from repro.core.sampled_softmax import full_softmax_loss
 from repro.launch.mesh import make_debug_mesh
 from repro.models import api
 from repro.sharding.rules import local_ctx, mesh_ctx, param_specs_for
-from repro.utils.compat import shard_map
 
 cfg = get_config("llama3-8b").reduced(m_negatives=64, sampler_block=32,
                                       vocab_size=500)
@@ -60,7 +59,7 @@ def fwd_eval(p, b):
         return dist.sharded_full_softmax_loss(head_full, h_l_, lab_,
                                               axis_name="model")
 
-    return shard_map(
+    return jax.shard_map(
         island, mesh=mesh, check_vma=False,
         in_specs=(P("model", "data"), P("data", None), P("data")),
         out_specs=P("data"))(head, h, labels)

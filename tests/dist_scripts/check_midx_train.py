@@ -21,7 +21,6 @@ from repro.optim import make_optimizer
 from repro.sharding.rules import mesh_ctx
 from repro.train.loop import fit
 from repro.train.step import init_train_state, make_train_step
-from repro.utils.compat import shard_map
 
 # ---- sharded loss == host reconstruction ------------------------------------
 # Stratified midx draw over a vocab-sharded head: each shard samples m/tp
@@ -43,7 +42,7 @@ def loss_fn(w_local, h_rep, labels_rep):
         jax.random.PRNGKey(42), axis_name="model")
 
 
-got = np.asarray(jax.jit(shard_map(
+got = np.asarray(jax.jit(jax.shard_map(
     loss_fn, mesh=mesh8, check_vma=False,
     in_specs=(P("model"), P(), P()), out_specs=P()))(w, h, labels))
 assert np.isfinite(got).all()

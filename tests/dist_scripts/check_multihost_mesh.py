@@ -16,7 +16,6 @@ from repro.launch.mesh import make_multihost_mesh
 from repro.optim import make_optimizer
 from repro.sharding.rules import mesh_ctx
 from repro.train.loop import fit
-from repro.utils.compat import shard_map
 
 # ---- topology ---------------------------------------------------------------
 mesh = make_multihost_mesh(hosts=4)  # 8 devices / 4 hosts -> 2 per host
@@ -38,7 +37,7 @@ def probe():
     return jnp.stack([idx, n, off]).reshape(1, 3)
 
 
-out = np.asarray(shard_map(probe, mesh=mesh, in_specs=(),
+out = np.asarray(jax.shard_map(probe, mesh=mesh, in_specs=(),
                            out_specs=P(AXES, None))())
 assert out.shape == (8, 3), out.shape
 # composed index enumerates devices row-major over (host, data, model)

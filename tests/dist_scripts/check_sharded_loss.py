@@ -10,7 +10,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core import distributed as dist
 from repro.core.sampled_softmax import full_softmax_loss
 from repro.core.samplers import BlockSampler, UniformSampler
-from repro.utils.compat import shard_map
 
 mesh = jax.make_mesh((8,), ("model",))
 n, d, T, m = 1024, 32, 16, 256
@@ -30,7 +29,7 @@ def loss_fn(w_local, h_rep, labels_rep):
         jax.random.PRNGKey(42), axis_name="model")
 
 
-loss_sharded = jax.jit(shard_map(
+loss_sharded = jax.jit(jax.shard_map(
     loss_fn, mesh=mesh, check_vma=False,
     in_specs=(P("model"), P(), P()),
     out_specs=P()))
@@ -42,7 +41,7 @@ print("full softmax loss:   ", np.asarray(ref.mean()))
 assert np.isfinite(np.asarray(loss)).all()
 
 # Full-softmax sharded eval must match the unsharded reference exactly.
-eval_sharded = jax.jit(shard_map(
+eval_sharded = jax.jit(jax.shard_map(
     lambda wl, hr, lr: dist.sharded_full_softmax_loss(
         wl, hr, lr, axis_name="model"),
     mesh=mesh, in_specs=(P("model"), P(), P()), out_specs=P()))
@@ -52,7 +51,7 @@ np.testing.assert_allclose(np.asarray(ev), np.asarray(ref), rtol=2e-5,
 print("sharded full softmax == reference OK")
 
 # Argmax agrees with dense argmax.
-am_sharded = jax.jit(shard_map(
+am_sharded = jax.jit(jax.shard_map(
     lambda wl, hr: dist.sharded_logits_argmax(wl, hr, axis_name="model"),
     mesh=mesh, in_specs=(P("model"), P()), out_specs=(P(), P())))
 ids, best = am_sharded(w, h)
@@ -77,7 +76,7 @@ def loss_impl(w_local, h_rep, labels_rep, impl, mask):
 for mask in (True, False):
     vals = {}
     for impl in ("auto", "einsum"):
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda wl, hr, lr, impl=impl, mask=mask: loss_impl(
                 wl, hr, lr, impl, mask),
             mesh=mesh, check_vma=False,
@@ -109,7 +108,7 @@ n_local = n // 8
 o_full = np.asarray(h @ w.T)
 pos_full = o_full[np.arange(T), np.asarray(labels)]
 for est_name in ("nce", "sampled-logistic"):
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda wl, hr, lr, e=est_name: est_loss(wl, hr, lr, e),
         mesh=mesh, check_vma=False,
         in_specs=(P("model"), P(), P()), out_specs=P()))
@@ -143,7 +142,7 @@ def loss_u(w_local, h_rep, labels_rep, key):
         axis_name="model")
 
 
-loss_u_sharded = jax.jit(shard_map(
+loss_u_sharded = jax.jit(jax.shard_map(
     loss_u, mesh=mesh, in_specs=(P("model"), P(), P(), P()),
     out_specs=P()))
 losses = []

@@ -31,7 +31,6 @@ from repro.launch.mesh import make_debug_mesh
 from repro.optim import make_optimizer
 from repro.sharding.rules import mesh_ctx
 from repro.train.step import init_train_state, make_train_step
-from repro.utils.compat import shard_map
 
 # --- part 1: sharded loss == single-host reconstruction ----------------------
 mesh = jax.make_mesh((8,), ("model",))
@@ -92,7 +91,7 @@ hit = union_gid == np.asarray(labels)[:, None]
 o_adj = np.where(hit, -np.inf, union_o - union_logq - np.log(m))
 
 for est_name in ("sampled-softmax", "sampled-logistic"):
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda wl, hr, lr, e=est_name: est_loss(wl, hr, lr, e),
         mesh=mesh, check_vma=False,
         in_specs=(P("model"), P(), P()), out_specs=P()))
@@ -108,7 +107,7 @@ for est_name in ("sampled-softmax", "sampled-logistic"):
 print("sharded tapas loss == single-host pool-union reconstruction OK")
 
 # Gradients flow through the pool all-gather back to the owning shard.
-f_sum = jax.jit(shard_map(
+f_sum = jax.jit(jax.shard_map(
     lambda wl, hr, lr: jnp.sum(est_loss(wl, hr, lr, "sampled-softmax")),
     mesh=mesh, check_vma=False,
     in_specs=(P("model"), P(), P()), out_specs=P()))

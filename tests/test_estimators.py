@@ -21,11 +21,12 @@ NAMES = ["sampled-softmax", "nce", "sampled-logistic", "full"]
 
 
 def _toy(t=6, n=24, d=8, m=10, collide=False):
-    key = jax.random.PRNGKey(0)
-    w = jax.random.normal(key, (n, d)) * 0.5
-    h = jax.random.normal(jax.random.fold_in(key, 1), (t, d))
-    labels = jax.random.randint(jax.random.fold_in(key, 2), (t,), 0, n)
-    ids = jax.random.randint(jax.random.fold_in(key, 3), (t, m), 0, n)
+    # numpy's generator: the toy does not move with JAX's PRNG defaults
+    rng = np.random.default_rng(0)
+    w = jnp.asarray(rng.standard_normal((n, d)) * 0.5, jnp.float32)
+    h = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, n, (t,)), jnp.int32)
+    ids = jnp.asarray(rng.integers(0, n, (t, m)), jnp.int32)
     if collide:  # force an accidental hit in slot 0 of every row
         ids = ids.at[:, 0].set(labels)
     logq = jnp.full((t, m), -np.log(n))

@@ -26,8 +26,6 @@ def test_scan_trip_count_correction():
     assert abs(res["flops"] - expected) / expected < 0.02
     # raw XLA undercounts by ~the trip count
     raw = compiled.cost_analysis()
-    if isinstance(raw, list):  # older jax: one dict per device
-        raw = raw[0]
     assert res["flops"] > 5 * raw["flops"]
 
 

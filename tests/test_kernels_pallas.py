@@ -42,11 +42,13 @@ def test_block_scores(t, n, r, dtype):
 @pytest.mark.parametrize("g,b,r", [(16, 8, 16), (100, 4, 8), (128, 32, 32),
                                    (1, 16, 8)])
 def test_leaf_scores(g, b, r, dtype):
+    """g queries, each drawing 3 of 5 leaves of a (5, b, r) table."""
     h = (jax.random.normal(jax.random.PRNGKey(g), (g, r)) * 0.5).astype(dtype)
-    rows = (jax.random.normal(jax.random.PRNGKey(b), (g, b, r)) * 0.5
-            ).astype(dtype)
-    got = ops.leaf_scores(h, rows, alpha=100.0)
-    want = ref.leaf_scores_ref(h, rows, 100.0)
+    table = (jax.random.normal(jax.random.PRNGKey(b), (5, b, r)) * 0.5
+             ).astype(dtype)
+    idx = jax.random.randint(jax.random.PRNGKey(r), (g, 3), 0, 5)
+    got = ops.leaf_scores(h, table, idx, alpha=100.0)
+    want = ref.leaf_scores_ref(h, table, idx, 100.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-2 if dtype == jnp.bfloat16 else 3e-4)
 
@@ -114,19 +116,20 @@ def test_sampled_loss_property(t, m, d, bf16):
        st.booleans())
 def test_leaf_scores_property(g, b, r, bf16):
     """Both modes of the leaf kernel (quadratic scores and raw dots) across
-    ragged draw counts, odd leaf widths, single rows, and bf16."""
+    ragged query counts, odd leaf widths, single rows, and bf16."""
     dtype = jnp.bfloat16 if bf16 else jnp.float32
     h = (jax.random.normal(jax.random.PRNGKey(g), (g, r)) * 0.5).astype(dtype)
-    rows = (jax.random.normal(jax.random.PRNGKey(b + 1), (g, b, r)) * 0.5
-            ).astype(dtype)
-    got = ops.leaf_scores(h, rows, alpha=100.0)
-    assert got.shape == (g, b) and got.dtype == jnp.float32
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(ref.leaf_scores_ref(h, rows, 100.0)),
-                               rtol=4e-2 if bf16 else 3e-4, atol=2e-2)
-    dots = ops.leaf_dots(h, rows)
+    table = (jax.random.normal(jax.random.PRNGKey(b + 1), (7, b, r)) * 0.5
+             ).astype(dtype)
+    idx = jax.random.randint(jax.random.PRNGKey(g + b), (g, 2), 0, 7)
+    got = ops.leaf_scores(h, table, idx, alpha=100.0)
+    assert got.shape == (g, 2, b) and got.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref.leaf_scores_ref(h, table, idx, 100.0)),
+        rtol=4e-2 if bf16 else 3e-4, atol=2e-2)
+    dots = ops.leaf_dots(h, table, idx)
     np.testing.assert_allclose(np.asarray(dots),
-                               np.asarray(ref.leaf_dots_ref(h, rows)),
+                               np.asarray(ref.leaf_dots_ref(h, table, idx)),
                                rtol=4e-2 if bf16 else 3e-4, atol=2e-2)
 
 

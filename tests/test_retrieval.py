@@ -42,6 +42,23 @@ def test_full_beam_matches_dense(n, leaf, cluster):
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("r", [16, 300], ids=["whole-space", "subspace"])
+def test_spectral_stats_match_dense_eigh(r):
+    """The spectral bound's top-s eigenpairs and residual cap agree with a
+    float64 dense eigendecomposition: exactly when r fits the subspace,
+    and after the power iterations on a decaying spectrum wider than it."""
+    rng = np.random.default_rng(r)
+    w = (rng.normal(size=(3, 2 * r, r))
+         * np.linspace(2.0, 0.1, r)).astype(np.float32)
+    z = np.einsum("nkr,nks->nrs", w, w)
+    (evecs,), (evals,) = retrieval.spectral_stats((jnp.asarray(z),), s=4)
+    want = np.linalg.eigvalsh(z.astype(np.float64))[:, ::-1][:, :5]
+    assert np.abs(np.asarray(evals) - want).max() < 1e-5 * want.max()
+    v = np.asarray(evecs, np.float64)                      # (3, 4, r)
+    resid = np.einsum("nrs,nks->nkr", z, v) - want[:, :4, None] * v
+    assert np.abs(resid).max() < 1e-4 * want[:, 0].max()
+
+
 def test_narrow_beam_bounds_are_sound():
     """Every class the narrow beam returns carries its exact dense logit
     (approximation can only DROP candidates, never mis-score them)."""
@@ -185,7 +202,8 @@ def test_leaf_dots_kernel_matches_ref():
     from repro.kernels import ops, ref
 
     h = jax.random.normal(jax.random.PRNGKey(0), (37, 16))
-    rows = jax.random.normal(jax.random.PRNGKey(1), (37, 8, 16))
-    np.testing.assert_allclose(np.asarray(ops.leaf_dots(h, rows)),
-                               np.asarray(ref.leaf_dots_ref(h, rows)),
+    table = jax.random.normal(jax.random.PRNGKey(1), (6, 8, 16))
+    idx = jax.random.randint(jax.random.PRNGKey(2), (37, 4), 0, 6)
+    np.testing.assert_allclose(np.asarray(ops.leaf_dots(h, table, idx)),
+                               np.asarray(ref.leaf_dots_ref(h, table, idx)),
                                rtol=1e-5, atol=1e-5)

@@ -282,3 +282,48 @@ def test_rejects_bad_query_dim_and_bad_buckets():
         eng.submit(np.zeros(D + 1, np.float32))
     with pytest.raises(ValueError, match="buckets"):
         ServingEngine(_decode_fn(w), D, K, buckets=(4, 2))
+
+
+def test_failing_decode_fails_every_request_and_stop_reraises():
+    """A decode that raises must not leave futures waiting: the in-flight
+    and queued requests fail with the error, later submissions fail at
+    once, and stop() re-raises the worker's exception."""
+
+    def broken(index, h):
+        raise ValueError("boom")
+
+    eng = ServingEngine(broken, D, K, buckets=(4,),
+                        max_wait_ms=20.0).start(warmup=False)
+    futs = [eng.submit(q) for q in _queries(4, 6)]
+    results = [f.result_wait(30.0) for f in futs]
+    late = eng.submit(_queries(5, 1)[0]).result_wait(1.0)
+    for r in results + [late]:
+        assert not r.ok and r.ids is None
+        assert r.error == "decode failed: ValueError: boom", r.error
+    assert eng.counters()["failed"] == 7
+    with pytest.raises(RuntimeError, match="serving worker died") as info:
+        eng.stop()
+    assert isinstance(info.value.__cause__, ValueError)
+
+
+def test_make_decode_fn_passes_head_as_argument():
+    """The head table enters the compiled decode as an input: closed over,
+    it would be baked into the program as an (n, d) constant."""
+    from repro.configs import get_config
+    from repro.serve.engine import make_decode_fn
+
+    cfg = get_config("youtube-dnn").reduced(vocab_size=N)
+    w = _table(0)
+    eng = ServingEngine(make_decode_fn(cfg, CTX, w, K), D, K, buckets=(2,))
+    lowered = eng._decode.lower(eng._fn, None, np.zeros((2, D), np.float32))
+    shapes = [a.shape for a in jax.tree_util.tree_leaves(lowered.args_info)]
+    assert (N, D) in shapes, shapes
+    eng.start()
+    try:
+        h = _queries(6, 1)[0]
+        r = eng.decode(h)
+    finally:
+        eng.stop()
+    ref_ids, _ = retrieval.dense_topk(w, h[None], K, n_valid=N)
+    assert r.ok
+    np.testing.assert_array_equal(r.ids, np.asarray(ref_ids)[0])

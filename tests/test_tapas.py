@@ -229,7 +229,11 @@ def test_facade_loss_and_grads():
 
 
 def test_local_train_steps():
-    """mesh=None train smoke: tapas through the full train step."""
+    """mesh=None train smoke: tapas through the full train step fits one
+    fixed batch.  Fresh random tokens and labels every step carry nothing
+    to learn, so the loss is followed on a repeated batch: over 8 steps it
+    must fall clearly below the first step's (measured drop ~0.9 nats for
+    this seed; the margin leaves room for the sampled loss's noise)."""
     from repro.optim import make_optimizer
     from repro.sharding.rules import local_ctx
     from repro.train.step import init_train_state, make_train_step
@@ -241,15 +245,15 @@ def test_local_train_steps():
     state = init_train_state(jax.random.PRNGKey(0), cfg, ctx, opt,
                              max_len=16)
     step = jax.jit(make_train_step(cfg, ctx, opt))
+    batch = {
+        "tokens": jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0,
+                                     cfg.vocab_size),
+        "labels": jax.random.randint(jax.random.PRNGKey(100), (2, 16), 0,
+                                     cfg.vocab_size),
+    }
     losses = []
-    for i in range(3):
-        batch = {
-            "tokens": jax.random.randint(jax.random.PRNGKey(i), (2, 16), 0,
-                                         cfg.vocab_size),
-            "labels": jax.random.randint(jax.random.PRNGKey(100 + i),
-                                         (2, 16), 0, cfg.vocab_size),
-        }
+    for i in range(8):
         state, metrics = step(state, batch, jax.random.PRNGKey(200 + i))
         losses.append(float(metrics["loss"]))
     assert np.isfinite(losses).all()
-    assert losses[-1] < losses[0], losses
+    assert losses[-1] < losses[0] - 0.3, losses
