@@ -1,0 +1,22 @@
+"""On-chip benchmark of the sampled-softmax training stack.
+
+One command runs one cell once::
+
+  python3 -m bench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+``BENCHMARK.json`` at the checkout's root names every cell, configuration
+and metric.  Each is found by name in files of its own:
+
+    bench/configs/<config>.json     model sizes, precision, optimizer
+    bench/workloads/<traffic>.json  the traffic mix a general generator reads
+    bench/checks/<cell>.json        the limits that decide ``correct``
+    bench/metrics/<metric>.py       a per-layer metric's reader
+
+so a cell, a configuration or a metric is added by adding files.  The
+yardstick lives here too: traffic generation (``traffic.py``), the weights
+(``weights.py``), the plain float32 reference (``reference.py``), the
+counting rule for model FLOPs (``flops.py``), the table of peaks
+(``peaks.py``) and the reduction from a profiler trace (``tracing.py``).
+From the program under test (``src/repro``) the benchmark takes only the
+train step, its optimizer and its parameter layout.
+"""
