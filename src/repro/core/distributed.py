@@ -98,7 +98,9 @@ def sharded_negative_sample(sampler: Sampler, state_local: Any, h: Array,
     assert m % tp == 0, f"m={m} must divide by the TP degree {tp}"
     m_local = m // tp
     key_local = jax.random.fold_in(key, axis_index(axis_name))
-    ids, logq_local = sampler.sample_batch(state_local, h, m_local, key_local)
+    with jax.named_scope("sampler_draw"):
+        ids, logq_local = sampler.sample_batch(state_local, h, m_local,
+                                               key_local)
     # q~_i = q_local(i) / tp  (global stratified probability)
     return ids, logq_local - jnp.log(jnp.asarray(tp, jnp.float32))
 
@@ -142,45 +144,48 @@ def sharded_sampled_softmax_loss(
     No tensor of size (T, n) is ever materialized; cross-shard communication
     is two psums of (T,)-vectors and one pmax.
     """
-    h32 = h.astype(jnp.float32)
-
     neg_ids, logq = sharded_negative_sample(sampler, state_local, h, m, key,
                                             axis_name)
-    pos = transform_logits(
-        _positive_logit(w_local, h, labels, axis_name, bias_local), abs_mode)
-    # local ids collide with the label iff label - shard offset matches.
-    labels_local = local_labels(w_local, labels, axis_name)
-    log_m = jnp.log(jnp.asarray(m, jnp.float32))
+    with jax.named_scope("head_loss"):
+        h32 = h.astype(jnp.float32)
+        pos = transform_logits(
+            _positive_logit(w_local, h, labels, axis_name, bias_local),
+            abs_mode)
+        # local ids collide with the label iff label - shard offset matches.
+        labels_local = local_labels(w_local, labels, axis_name)
+        log_m = jnp.log(jnp.asarray(m, jnp.float32))
 
-    if neg_ids.ndim == 2 and impl != "einsum":
-        # eq. 2 with stratified correction: E[count] = m_local*q_local = m*q~.
-        corr = (logq + log_m).astype(jnp.float32)
-        if mask_accidental_hits:
-            corr = jnp.where(neg_ids == labels_local[:, None], ops.MASK_CORR,
-                             corr)
-        biasg = bias_local[neg_ids] if bias_local is not None else None
-        # per-token logsumexp over this shard's corrected negatives only.
-        lse_local = ops.fused_head_lse(
-            w_local, h32, neg_ids, corr, biasg, abs_mode=abs_mode,
-            impl="auto" if impl == "fused" else impl)
-        c = lax.pmax(jnp.maximum(lax.stop_gradient(lse_local),
-                                 lax.stop_gradient(pos)), axis_name)
-        sumexp = (lax.psum(jnp.exp(lse_local - c), axis_name)
-                  + jnp.exp(pos - c))
+        if neg_ids.ndim == 2 and impl != "einsum":
+            # eq. 2, stratified: E[count] = m_local*q_local = m*q~.
+            corr = (logq + log_m).astype(jnp.float32)
+            if mask_accidental_hits:
+                corr = jnp.where(neg_ids == labels_local[:, None],
+                                 ops.MASK_CORR, corr)
+            biasg = bias_local[neg_ids] if bias_local is not None else None
+            # per-token logsumexp over this shard's corrected negatives only.
+            lse_local = ops.fused_head_lse(
+                w_local, h32, neg_ids, corr, biasg, abs_mode=abs_mode,
+                impl="auto" if impl == "fused" else impl)
+            c = lax.pmax(jnp.maximum(lax.stop_gradient(lse_local),
+                                     lax.stop_gradient(pos)), axis_name)
+            sumexp = (lax.psum(jnp.exp(lse_local - c), axis_name)
+                      + jnp.exp(pos - c))
+            return jnp.log(sumexp) + c - pos
+
+        o_adj = _corrected_neg_logits(
+            w_local, h32, labels, neg_ids, logq, m, axis_name=axis_name,
+            abs_mode=abs_mode, bias_local=bias_local,
+            mask_hits=mask_accidental_hits)
+
+        # Numerically stable global logsumexp over [pos, all shards'
+        # negatives].  The shift constant needs no gradient (it cancels
+        # analytically).
+        local_max = lax.stop_gradient(jnp.max(o_adj, axis=-1))
+        c = lax.pmax(jnp.maximum(local_max, lax.stop_gradient(pos)),
+                     axis_name)
+        sumexp_local = jnp.sum(jnp.exp(o_adj - c[:, None]), axis=-1)
+        sumexp = lax.psum(sumexp_local, axis_name) + jnp.exp(pos - c)
         return jnp.log(sumexp) + c - pos
-
-    o_adj = _corrected_neg_logits(
-        w_local, h32, labels, neg_ids, logq, m, axis_name=axis_name,
-        abs_mode=abs_mode, bias_local=bias_local,
-        mask_hits=mask_accidental_hits)
-
-    # Numerically stable global logsumexp over [pos, all shards' negatives].
-    # The shift constant needs no gradient (it cancels analytically).
-    local_max = lax.stop_gradient(jnp.max(o_adj, axis=-1))
-    c = lax.pmax(jnp.maximum(local_max, lax.stop_gradient(pos)), axis_name)
-    sumexp_local = jnp.sum(jnp.exp(o_adj - c[:, None]), axis=-1)
-    sumexp = lax.psum(sumexp_local, axis_name) + jnp.exp(pos - c)
-    return jnp.log(sumexp) + c - pos
 
 
 def _corrected_neg_logits(w_local: Array, h32: Array, labels: Array,
@@ -254,12 +259,13 @@ def sharded_tapas_negatives(sampler: Sampler, state_local: Any,
     k_pool, k_draw = jax.random.split(key)
     k_pool_local = jax.random.fold_in(k_pool, axis_index(axis_name))
     base_rt = state_local["base"]
-    if sampler.base.shares_negatives:
-        pids, lq1 = sampler.base.sample_batch(base_rt, h, p_local,
-                                              k_pool_local)
-    else:
-        pids, lq1 = sampler.base.sample(base_rt, jnp.mean(h, axis=0),
-                                        p_local, k_pool_local)
+    with jax.named_scope("sampler_draw"):
+        if sampler.base.shares_negatives:
+            pids, lq1 = sampler.base.sample_batch(base_rt, h, p_local,
+                                                  k_pool_local)
+        else:
+            pids, lq1 = sampler.base.sample(base_rt, jnp.mean(h, axis=0),
+                                            p_local, k_pool_local)
     logpi_l = pool_log_inclusion(lq1, p_local)
     gids_l = pids + local_vocab_offset(w_local.shape[0], axis_name)
     pool_w = lax.all_gather(w_local[pids], axis_name, axis=0, tiled=True)
@@ -276,7 +282,8 @@ def sharded_tapas_negatives(sampler: Sampler, state_local: Any,
     o_sg = lax.stop_gradient(o) / sampler.tau
     s = o_sg - (pool_logpi + jnp.log(mult.astype(jnp.float32)))[None, :]
     k_shard = jax.random.fold_in(k_draw, axis_index(axis_name))
-    slots = categorical_rows(k_shard, s, m_local)
+    with jax.named_scope("sampler_draw"):
+        slots = categorical_rows(k_shard, s, m_local)
     logq = (jnp.take_along_axis(o_sg, slots, axis=1)
             - jax.nn.logsumexp(s, axis=-1)[:, None])
     return pool_gids, o, slots, logq

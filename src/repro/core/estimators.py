@@ -198,16 +198,19 @@ def local_sampled_loss(est: Estimator, sampler, w: Array, h: Array,
     if not est.needs_sampling:
         return loss_from_embeddings(est, w, h, labels, None, None,
                                     abs_mode=abs_mode, bias=bias, impl=impl)
-    runtime = sampler.island_runtime(state, jax.lax.stop_gradient(w),
-                                     n_valid)
-    runtime = jax.tree_util.tree_map(jax.lax.stop_gradient, runtime)
-    neg_ids, logq = sampler.sample_batch(runtime, h, m, key)
+    with jax.named_scope("sampler_refresh"):
+        runtime = sampler.island_runtime(state, jax.lax.stop_gradient(w),
+                                         n_valid)
+        runtime = jax.tree_util.tree_map(jax.lax.stop_gradient, runtime)
+    with jax.named_scope("sampler_draw"):
+        neg_ids, logq = sampler.sample_batch(runtime, h, m, key)
     return loss_from_embeddings(
         est, w, h, labels, jax.lax.stop_gradient(neg_ids),
         jax.lax.stop_gradient(logq), abs_mode=abs_mode, bias=bias,
         impl=impl)
 
 
+@jax.named_scope("head_loss")
 def loss_from_embeddings(
     est: Estimator, w: Array, h: Array, labels: Array,
     neg_ids: Array | None, logq: Array | None, *, abs_mode: bool = False,

@@ -55,6 +55,7 @@ def hidden_width(cfg: ArchConfig) -> int:
     return cfg.d_model
 
 
+@jax.named_scope("backbone")
 def backbone_hidden(params: Params, batch: dict[str, Array], cfg: ArchConfig,
                     ctx: ShardCtx) -> tuple[Array, Array, Array]:
     """Forward to the last hidden layer; flatten (example, feature).

@@ -166,6 +166,7 @@ def _qkv(p: Params, x: Array, cfg: ArchConfig, positions: Array,
     return q, k, v
 
 
+@jax.named_scope("attention")
 def chunked_attention(q: Array, k: Array, v: Array, *, causal: bool,
                       chunk: int, q_offset: int = 0) -> Array:
     """Online-softmax attention over KV chunks (flash-style, pure jnp).

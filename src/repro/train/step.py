@@ -116,6 +116,7 @@ def make_refresh_fn(cfg: ArchConfig, ctx: ShardCtx
         n_valid = jnp.clip(cfg.vocab_size - my * v_l, 0, v_l)
         return sampler.build_stats(head_full, n_valid, const)
 
+    @jax.named_scope("sampler_refresh")
     def refresh_fn(head: Array, sampler_state: SamplerState) -> SamplerState:
         if not carries_stats:
             return sampler_state
@@ -164,8 +165,9 @@ def _make_head_loss(cfg: ArchConfig, ctx: ShardCtx
         the sampler hydrates its carried pytree, rebuilds from the gathered
         head, or (multi-stage families) keeps the head table for pool
         re-scoring (Sampler.island_runtime)."""
-        return sampler.island_runtime(sampler_state,
-                                      lax.stop_gradient(head_full), n_valid)
+        with jax.named_scope("sampler_refresh"):
+            return sampler.island_runtime(
+                sampler_state, lax.stop_gradient(head_full), n_valid)
 
     def head_island(head, h2d, labels, stats, const, key):
         """Runs per-(data,model) shard.  head: (v_l, d_l) local;
@@ -272,6 +274,7 @@ def make_train_step(cfg: ArchConfig, ctx: ShardCtx, opt: GradientTransform,
         new = sampler.build_stats(head_full, n_valid, const)
         return _merge_refresh(new, stats, refresh)
 
+    @jax.named_scope("sampler_refresh")
     def refresh_state(head, sampler_state: SamplerState, refresh
                       ) -> SamplerState:
         if not carries_stats:
@@ -354,8 +357,10 @@ def make_train_step(cfg: ArchConfig, ctx: ShardCtx, opt: GradientTransform,
                 body, acc0, (mbs, keys))
             total, loss, aux = total / mu, loss / mu, aux / mu
             grads = jax.tree_util.tree_map(lambda g_: g_ / mu, grads)
-        updates, opt_state = opt.update(grads, state.opt_state, state.params)
-        params = apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, state.opt_state,
+                                            state.params)
+            params = apply_updates(state.params, updates)
         new_state = TrainState(
             params=params,
             opt_state=opt_state,
