@@ -12,13 +12,23 @@ trace is found by name among the instructions of the compiled step's
 optimized HLO text, and charged its self time.  Prints one JSON line: ms
 per step of each scope, of ``remat`` (recompute under ``jax.checkpoint``,
 whatever its scope) and of the busy time, with the share of busy time
-whose op was found in the text.  Exits non-zero off a TPU.
+whose op was found in the text, and ``attention_kernel_share``: the
+share of ``attention``'s time spent in Pallas kernels (the splash flash
+kernels where the program takes them).  Exits non-zero off a TPU.
+
+A Pallas kernel's custom call carries its ``kernel_metadata`` attribute
+as JSON with line breaks, so the instruction spans several lines of the
+HLO text and its ``op_name`` lies on a later line than its name; the text
+is read with each instruction joined onto one line
+(``one_line_instructions``), else every kernel would read as
+``unscoped``.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import pathlib
+import re
 import shutil
 import sys
 
@@ -26,6 +36,63 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
 from bench import harness, scopes, tracing  # noqa: E402
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+def _open_braces(text: str) -> int:
+    """Braces opened less braces closed in ``text``, outside quotes."""
+    depth, quoted, escaped = 0, False, False
+    for ch in text:
+        if escaped:
+            escaped = False
+        elif ch == "\\":
+            escaped = True
+        elif ch == '"':
+            quoted = not quoted
+        elif not quoted:
+            depth += (ch == "{") - (ch == "}")
+    return depth
+
+
+def one_line_instructions(hlo_text: str) -> str:
+    """``hlo_text`` with every instruction that spans several lines (its
+    braces left open at the line's end) joined onto one line."""
+    lines: list[str] = []
+    open_ = False
+    for line in hlo_text.splitlines():
+        if open_:
+            lines[-1] += line
+        else:
+            lines.append(line)
+            if not _INSTRUCTION.match(line):
+                continue
+        open_ = _open_braces(lines[-1]) > 0
+    return "\n".join(lines)
+
+
+def kernel_names(hlo_text: str) -> set[str]:
+    """The instructions of one-line HLO text that call a Pallas kernel."""
+    return {m.group(1) for line in hlo_text.splitlines()
+            if _KERNEL in line and (m := _INSTRUCTION.match(line))}
+
+
+def kernel_share(run: dict, scope: str) -> float | None:
+    """The share of ``scope``'s self time, over every chip, spent in Pallas
+    kernel calls; None where the scope has no time."""
+    text = run["hlo_text"]
+    kernels = kernel_names(text)
+    scope_of = {name: scopes.scope_of(path)
+                for name, path in scopes.op_paths(text).items()}
+    total = in_kernels = 0.0
+    for ops in run["trace"].devices.values():
+        ops = tracing.clip(ops, run["lo"], run["hi"])
+        for (_, _, name), t in zip(ops, scopes.self_times(ops)):
+            if scope_of.get(name, scopes.UNSCOPED) == scope:
+                total += t
+                in_kernels += t if name in kernels else 0.0
+    return in_kernels / total if total else None
 
 
 def main(argv=None) -> int:
@@ -53,7 +120,7 @@ def main(argv=None) -> int:
     shutil.rmtree(log_dir, ignore_errors=True)
     lo, hi = trace.window()
     run = dict(kind="train", steps=w["steps"], trace=trace, lo=lo, hi=hi,
-               hlo_text=setup.compiled.as_text())
+               hlo_text=one_line_instructions(setup.compiled.as_text()))
     names = scopes.LAYER_SCOPES + (scopes.UNSCOPED, "remat")
     out = {name: scopes.device_ms(run, name) for name in names}
     chips = scopes.splits(run)
@@ -61,6 +128,7 @@ def main(argv=None) -> int:
     out.update(
         busy=busy / len(chips) / w["steps"] / 1e6,
         mapped_share=sum(s["mapped"] for s in chips) / busy,
+        attention_kernel_share=kernel_share(run, "attention"),
         steps=w["steps"], window_s=(hi - lo) / 1e9,
         targets_per_s=w["steps"] * train.targets_per_batch(cell.traffic)
         / w["seconds"], device=device)

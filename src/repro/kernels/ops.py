@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.kernels.block_scores import block_scores as _block_scores
 from repro.kernels.flash_attention import flash_attention as _flash
@@ -351,4 +352,55 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     out = _flash(qp, kp, vp, causal=causal, q_tile=q_tile, kv_tile=kv_tile,
                  s_valid=s, interpret=_interpret())
     out = out[:, :s]
+    return jnp.moveaxis(out.reshape(b, h_heads, s, hd), 1, 2)
+
+
+# --- causal self-attention, forward + backward (jax's splash kernels) -------
+
+
+def splash_block(s: int) -> int:
+    """The size of every block of the splash kernels at sequence length s
+    (a multiple of 128): the largest of 512, 256, 128 that divides it.
+    At S 2048, 24 query and 2 KV heads of 128 on a TPU v5e, 512 with the
+    fused backward beat 256, 1024 and mixed sizes (PERF.md, PR 14)."""
+    return next(b for b in (512, 256, 128) if s % b == 0)
+
+
+@functools.lru_cache(maxsize=16)
+def _splash_kernel(s: int, group: int, interpret: bool):
+    """The splash MQA kernel for one KV head and its ``group`` query heads
+    under a causal mask, built once per static shape.  Its mask tables are
+    made eagerly, so a kernel built while a step is traced holds no
+    tracer."""
+    b = splash_block(s)
+    sizes = splash.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b,
+        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+        use_fused_bwd_kernel=True)
+    mask = splash.MultiHeadMask([splash.CausalMask((s, s))] * group)
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mqa_single_device(
+            mask, block_sizes=sizes, interpret=interpret)
+
+
+def causal_attention(q: Array, k: Array, v: Array) -> Array:
+    """Causal self-attention by the splash flash kernels (a forward and a
+    fused dq + dkv backward behind a custom VJP; blocks above the diagonal
+    skipped).
+
+    q: (B, S, H, hd); k, v: (B, S, KV, hd) with H a multiple of KV (GQA)
+    and S a multiple of 128 -> (B, S, H, hd).  q is scaled by 1/sqrt(hd)
+    in float32 and rounded back to its dtype, so the MXU takes q and k in
+    that dtype; each KV head is shared by its query group (no head
+    expansion).  The softmax statistics and accumulators are float32
+    inside the kernels."""
+    b, s, h_heads, hd = q.shape
+    kv = k.shape[2]
+    group = h_heads // kv
+    kernel = _splash_kernel(s, group, _interpret())
+    q = (q * (1.0 / np.sqrt(hd))).astype(q.dtype)
+    qt = jnp.moveaxis(q, 1, 2).reshape(b * kv, group, s, hd)
+    kt = jnp.moveaxis(k, 1, 2).reshape(b * kv, s, hd)
+    vt = jnp.moveaxis(v, 1, 2).reshape(b * kv, s, hd)
+    out = jax.vmap(kernel)(qt, kt, vt)
     return jnp.moveaxis(out.reshape(b, h_heads, s, hd), 1, 2)

@@ -7,7 +7,9 @@ Conventions:
   * every apply function takes a ShardCtx for activation constraints; pass
     ``local_ctx()`` for single-device smoke use;
   * attention is chunked online-softmax (flash-style) in pure jnp — this is
-    also the reference for the Pallas kernel in repro/kernels.
+    also the reference for the Pallas kernels in repro/kernels; on one TPU
+    device, causal self-attention whose shapes the splash kernels take runs
+    on them instead (`flash_applies`).
 """
 from __future__ import annotations
 
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels import ops as kops
 from repro.sharding.rules import ShardCtx
 
 Array = jax.Array
@@ -223,6 +226,32 @@ def chunked_attention(q: Array, k: Array, v: Array, *, causal: bool,
     return out.reshape(B, Sq, H, hv).astype(q.dtype)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def flash_applies(q: Array, k: Array, v: Array, *, causal: bool,
+                  ctx: ShardCtx) -> bool:
+    """Whether self-attention over these q, k, v runs on the splash
+    kernels (`flash_attention`): on a TPU, on one device, causal with
+    Sq == Sk, v's head width equal to q's, and the head width and the
+    sequence both multiples of 128.  Every other input takes
+    `chunked_attention`."""
+    s, hd = q.shape[1], q.shape[-1]
+    return (causal and _on_tpu()
+            and (ctx.mesh is None or ctx.mesh.size == 1)
+            and k.shape[1] == s and v.shape[-1] == hd
+            and hd % 128 == 0 and s % 128 == 0)
+
+
+@jax.named_scope("attention")
+def flash_attention(q: Array, k: Array, v: Array) -> Array:
+    """Causal self-attention on the splash flash kernels, forward and
+    backward (`kernels/ops.py::causal_attention`); the same shapes and
+    result as `chunked_attention(q, k, v, causal=True, ...)`."""
+    return kops.causal_attention(q, k, v)
+
+
 def attn_forward(p: Params, x: Array, positions: Array, cfg: ArchConfig,
                  ctx: ShardCtx, *, causal: bool = True,
                  kv_override: tuple[Array, Array] | None = None) -> Array:
@@ -230,7 +259,11 @@ def attn_forward(p: Params, x: Array, positions: Array, cfg: ArchConfig,
     q, k, v = _qkv(p, x, cfg, positions, ctx, rope_on=not cfg.learned_pos)
     if kv_override is not None:
         k, v = kv_override
-    out = chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk)
+    if kv_override is None and flash_applies(q, k, v, causal=causal,
+                                             ctx=ctx):
+        out = flash_attention(q, k, v)
+    else:
+        out = chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk)
     out = ctx.act(out, "bsh.")
     B, S = x.shape[0], x.shape[1]
     dt = _dtype(cfg)

@@ -1,6 +1,7 @@
 """Every Pallas kernel the chip path reaches, compiled for a described TPU
 v5e chip at the widths ``chip_smoke.py`` runs (starcoder2-3b: d_model 3072,
-vocab 49152, sampler rank 64, leaf 512; serving leaf 4096).
+vocab 49152, sampler rank 64, leaf 512; serving leaf 4096; 24 query and 2
+KV heads of 128 over 2048 tokens for the splash attention kernels).
 
 Nothing runs: each test lowers the ops.py wrapper — its own tiling — for
 one chip of a ``v5e:2x2`` topology and lets the TPU compiler accept or
@@ -9,7 +10,9 @@ chip ``ops._interpret()`` would pick interpret mode, so each test
 patches it to compiled mode.  The topology is described inside a fixture
 (never at import), and where it cannot be described the tests skip.
 """
+import importlib.util
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.models import layers
 
 D_MODEL = 3072
 VOCAB = 49_152
@@ -26,6 +30,7 @@ N_LEAVES = 128     # next_pow2(VOCAB / LEAF)
 T, M = 256, 64     # chip_smoke's tree-sampler draws: T queries x m each
 SERVE_LEAF = 4096  # retrieval.default_leaf_size(VOCAB, D_MODEL)
 SERVE_T = 8        # largest serving bucket
+SEQ, HEADS, KV_HEADS, HEAD_DIM = 2048, 24, 2, 128
 
 
 @pytest.fixture(scope="module")
@@ -125,3 +130,37 @@ def test_fused_head_refuses_uncompilable_pallas():
     assert ops.resolve_fused_impl("auto", VOCAB, D_MODEL) == "chunked"
     with pytest.raises(ValueError, match="FUSED_HEAD_VMEM_BYTES"):
         ops.resolve_fused_impl("pallas", VOCAB, D_MODEL)
+
+
+def test_splash_attention_fwd_bwd_compile_in_the_attention_scope(
+        one_chip, compiled_kernels):
+    """The kernel path of ``layers.flash_attention`` (forward, recompute
+    and fused backward) in a checkpointed layer scan, as the train step
+    runs it; every kernel call maps to the ``attention`` scope once
+    ``scripts/scope_split.py`` joins its multi-line HLO instructions."""
+    root = pathlib.Path(__file__).resolve().parent.parent
+    spec = importlib.util.spec_from_file_location(
+        "scope_split", root / "scripts" / "scope_split.py")
+    scope_split = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(scope_split)
+    from bench import scopes
+
+    def loss(q, k, v):
+        def body(x, _):
+            return (x + layers.flash_attention(x, k, v)).astype(x.dtype), None
+        body = jax.checkpoint(body, prevent_cse=False)
+        with jax.named_scope("backbone"):
+            out, _ = jax.lax.scan(body, q, None, length=2)
+        return jnp.sum(out.astype(jnp.float32))
+
+    bf16 = jnp.bfloat16
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        _sds(one_chip, (1, SEQ, HEADS, HEAD_DIM), bf16),
+                        _sds(one_chip, (1, SEQ, KV_HEADS, HEAD_DIM), bf16),
+                        _sds(one_chip, (1, SEQ, KV_HEADS, HEAD_DIM), bf16))
+    text = scope_split.one_line_instructions(compiled.as_text())
+    kernels = scope_split.kernel_names(text)
+    paths = scopes.op_paths(text)
+    assert len(kernels) >= 3  # forward, its recompute, fused backward
+    assert {scopes.scope_of(paths[n]) for n in kernels} == {"attention"}
+    assert any(scopes.REMAT_MARK in paths[n].split("/") for n in kernels)
