@@ -4,39 +4,46 @@ One rule for every train cell (the arithmetic of
 ``benchmarks/roofline.py::active_params`` / ``model_flops``, extended with
 attention and the sampled head):
 
-* counted: 6 x the backbone's matmul parameters per target,
-  causal attention at 6 * L * S * d per token, and the sampled head at
-  6 * (1 + m) * d per target (the positive row plus the m negative rows it
-  scores; with shared negatives each target scores the m shared rows);
-* not counted: the sampler's statistics, the draws, the optimizer and any
-  recomputation under remat.  Those are overhead, not model work, so a
-  change that removes overhead raises the share and none can lift it
-  above the chip's peak.
+* counted: 6 x the backbone's matmul parameters per target, the
+  backbone's attention where it has one (causal: 6 * L * S * d per token),
+  and the sampled head at 6 * (1 + m) * d per target (the positive row
+  plus the m negative rows it scores; with shared negatives each target
+  scores the m shared rows);
+* not counted: the sampler's statistics, the draws, embedding lookups and
+  bags, the optimizer and any recomputation under remat.  Those are
+  overhead, not model work, so a change that removes overhead raises the
+  share and none can lift it above the chip's peak.
 
-``cfg`` is a configuration file's dict (``bench/configs/<name>.json``).
+Each family gives its part, its matmul parameters and its attention, in
+``bench/families/<family>.py``.  ``cfg`` is a configuration file's dict
+(``bench/configs/<name>.json``).
 """
 from __future__ import annotations
 
 
+def head(m: int, width: int) -> float:
+    """The sampled head's FLOPs per target: the positive and m negative
+    rows of ``width``, forward and backward."""
+    return 6.0 * (1 + m) * width
+
+
 def backbone_matmul_params(cfg: dict) -> int:
     """Matmul parameters of the backbone (no embedding, no head, no norms
-    or biases)."""
-    d = cfg["d_model"]
-    if cfg["family"] != "dense":
-        raise ValueError(f"no FLOP rule for family {cfg['family']!r}")
-    hd = cfg.get("head_dim") or d // cfg["n_heads"]
-    attn = (d * cfg["n_heads"] * hd + 2 * d * cfg["n_kv_heads"] * hd
-            + cfg["n_heads"] * hd * d)
-    mlp = (3 if cfg.get("act", "silu") == "silu" else 2) * d * cfg["d_ff"]
-    return cfg["n_layers"] * (attn + mlp)
+    or biases), by the configuration's family."""
+    from bench import families
+
+    return families.load(cfg).matmul_params(cfg)
 
 
-def flops_per_target(cfg: dict, seq_len: int = 0) -> float:
-    """Model FLOPs (forward + backward) per softmax target."""
-    d = cfg["d_model"]
-    return (6.0 * backbone_matmul_params(cfg)
-            + 6.0 * cfg["n_layers"] * seq_len * d
-            + 6.0 * (1 + cfg["m_negatives"]) * d)
+def flops_per_target(cfg: dict, mix: dict | int = 0) -> float:
+    """Model FLOPs (forward + backward) per softmax target of a train cell
+    whose traffic is ``mix``, by the configuration's family; a bare number
+    stands for an LM mix's ``seq_len``."""
+    from bench import families
+
+    if not isinstance(mix, dict):
+        mix = {"seq_len": mix}
+    return families.load(cfg).flops_per_target(cfg, mix)
 
 
 def mfu_share(flops_per_step: float, seconds_per_step: float, chips: int,

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import compare, flops, reference, traffic, weights
+from bench import compare, families, flops, reference, weights
 from bench.harness import TRACE_DIR
 from bench.tracing import capture
 
@@ -52,16 +52,17 @@ def optimizer_settings(cfg: dict) -> dict:
 
 
 def make_ring(cfg: dict, mix: dict, key):
-    if mix["generator"] == "markov_lm":
-        return traffic.markov_lm_ring(
-            key, vocab_size=cfg["vocab_size"], batch=mix["batch"],
-            seq_len=mix["seq_len"], ring=mix["ring"], rank=mix["markov_rank"],
-            temperature=mix["temperature"])
-    raise ValueError(f"unknown training generator {mix['generator']!r}")
+    """The mix's ring of batches, by the configuration's family."""
+    return families.load(cfg).ring(cfg, mix, key)
 
 
-def targets_per_batch(mix: dict) -> int:
-    return mix["batch"] * mix["seq_len"]
+def targets_per_batch(mix: dict, cfg: dict | None = None) -> int:
+    """Softmax targets in one batch of ``mix``, by the configuration's
+    family, or without one by the family that makes the mix's
+    generator."""
+    family = (families.load(cfg) if cfg is not None
+              else families.with_generator(mix["generator"]))
+    return family.targets_per_batch(cfg, mix)
 
 
 class Inputs:
@@ -91,7 +92,7 @@ class Inputs:
         r = self.cfg.get("sampler_proj_rank")
         if not r:
             return None
-        d = self.cfg["d_model"]
+        d = families.load(self.cfg).head_table(self.layout).shape[-1]
         return jax.jit(lambda k: jax.random.normal(k, (r, d), jnp.float32)
                        / np.sqrt(r))(self.keys[K_SAMPLER])
 
@@ -147,7 +148,7 @@ class Setup:
                        for s in jax.tree_util.tree_leaves(inp.layout))
         log(f"[train] {cfg['name']}: {n_params / 1e9:.4f}B params, "
             f"sampler {arch.sampler} m={arch.m_negatives}, batch "
-            f"{targets_per_batch(mix)} targets, ring {mix['ring']}")
+            f"{targets_per_batch(mix, cfg)} targets, ring {mix['ring']}")
         self.state = state
 
     def first_steps(self, keep_params1: bool = False) -> dict:
@@ -247,7 +248,7 @@ def run(cell, args, env) -> dict:
         w = window(setup, seconds)
     env.compiles.on = False
     peak = env.memory_peak()
-    tpb = targets_per_batch(cell.traffic)
+    tpb = targets_per_batch(cell.traffic, cell.config)
     rate = w["steps"] * tpb / w["seconds"] if w["steps"] else 0.0
     log(f"[train] window: {w['steps']} steps ({w['ahead']} in flight) in "
         f"{w['seconds']:.3f} s, "
@@ -256,6 +257,7 @@ def run(cell, args, env) -> dict:
 
     # the check: free the program's state, then the reference
     batches, keys = setup.ring[:2], setup.step_keys[:2]
+    hlo_text = setup.compiled.as_text() if traced else None
     del setup
     gc.collect()
     t_ref = time.perf_counter()
@@ -273,15 +275,15 @@ def run(cell, args, env) -> dict:
     numbers = compare.train_numbers(prog, ref)
     log(f"[check] numbers {numbers}")
     correct, checks = compare.verdict(numbers, cell.checks)
-    fpt = flops.flops_per_target(cell.config, cell.traffic["seq_len"])
+    fpt = flops.flops_per_target(cell.config, cell.traffic)
     return {
         "correct": correct and w["nonfinite"] == 0 and w["steps"] > 0,
         "attempted": w["steps"], "failed": w["nonfinite"],
         "setup_end": t_setup_end, "peak": peak, "checks": checks,
         "e2e": {"train_targets_per_s": rate},
         "layer_input": {"steps": w["steps"], "seconds": w["seconds"],
-                        "flops_per_step": fpt * tpb,
-                        "kind": "train"},
+                        "flops_per_step": fpt * tpb, "kind": "train",
+                        "hlo_text": hlo_text},
     }
 
 

@@ -1,0 +1,8 @@
+"""device_ms.train.optimizer: device self time of the train step's
+``optimizer`` scope per window step, in ms, the mean over chips, from
+the profiler trace and the compiled step's HLO text (bench/scopes.py)."""
+from bench.scopes import device_ms
+
+
+def read(run):
+    return device_ms(run, "optimizer")

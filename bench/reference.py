@@ -3,20 +3,23 @@
 Written from the models' descriptions in straightforward ``jax.numpy``;
 nothing here imports the program under test or takes anything it made.
 The inputs (weights, the sampler's projection, batches, step keys) come
-from the benchmark's own seeded generators (``weights.py``,
-``traffic.py``), the same ones that fed the program.
+from the benchmark's own seeded generators (``weights.py``, the family's
+``ring``), the same ones that fed the program.
 
-* ``lm_hidden``: a pre-norm decoder (layernorm, GQA attention with RoPE
-  and q/k/v biases, tanh-GELU MLP) -> last hidden states.
+* the backbone: ``hidden`` of the configuration's family
+  (``bench/families/<family>.py``) -> (h (T, d), labels (T,)).
 * ``draw``: the two-level quadratic-kernel sampler (paper §3.2 in its
   two-level form: one block by block mass, then one class inside it by
-  exact kernel score; negatives shared over the batch).  It follows the draw
-  protocol of the kernel sampler (which key draws the block, which the
-  class, Gumbel-max or inverse-CDF) so that its draws are the program's
-  own up to floating-point ties, and reports the EXACT log q of each draw
-  from per-class scores.
+  exact kernel score), chosen by the configuration's ``sampler``:
+  ``block-quadratic-shared`` draws one set of m negatives for the batch
+  from the batch-summed kernel; ``block-quadratic`` draws m i.i.d.
+  negatives per example.  It follows the draw protocol of the kernel
+  sampler (which key draws the block, which the class, Gumbel-max or
+  inverse-CDF) so that its draws are the program's own up to
+  floating-point ties, and reports the EXACT log q of each draw from
+  per-class scores over the whole catalog.
 * ``sampled_loss``: eq. 2-3 (corrected negatives, accidental hits
-  masked).
+  masked), over |o| where the configuration has ``abs_softmax`` (eq. 11).
 * ``train_steps``: two steps of global-norm clipping (1.0) + AdamW from
   the seed, returning each step's loss, the first clipped gradient's norm
   per leaf and the parameters' change after the two, per leaf.  Two steps
@@ -30,8 +33,10 @@ with a per-tensor scale, and each cotangent that enters a backward matmul
 to float8 e5m2, so the backward matmuls see the rounded operands and the
 rounded cotangents; sums, norms and the softmax stay in float32.
 ``mode="bf16"`` is a witness that computes as the configuration states:
-matmul operands, results and stored activations in bfloat16.  Parameters
-are rounded to their stored type after each update.
+matmul operands in bfloat16, and for a model whose ``dtype`` is bfloat16
+results and stored activations too (a float32 model on the TPU's default
+precision rounds a matmul's operands and keeps its result in float32).
+Parameters are rounded to their stored type after each update.
 """
 from __future__ import annotations
 
@@ -41,6 +46,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import families
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = {jnp.float8_e4m3fn: 448.0, jnp.float8_e5m2: 57344.0}
@@ -113,88 +120,20 @@ def stored(mode: str, x):
     return _round_bf16(x) if mode == "bf16" else x
 
 
-def einsum(mode: str, spec: str, *xs):
+def einsum(mode: str, spec: str, *xs, store: bool = True):
+    """A matmul as the mode computes it.  ``store=False`` keeps a bf16
+    mode's result in float32, as a float32 model computes (``stores``)."""
     r = rounder(mode)
     out = jnp.einsum(spec, *(r(x) for x in xs), precision=HIGHEST,
                      preferred_element_type=jnp.float32)
-    return stored(mode, round_grad_fp8(out)) if mode == "fp8" else \
-        stored(mode, out)
+    if mode == "fp8":
+        return stored(mode, round_grad_fp8(out))
+    return stored(mode, out) if store else out
 
 
-# --- backbones --------------------------------------------------------------
-
-
-def layer_norm(x, p, eps: float = 1e-5):
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    return (x - mean) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def gelu_tanh(x):
-    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
-                                     * (x + 0.044715 * x ** 3)))
-
-
-def rope(x, theta: float):
-    """x: (B, S, H, hd); rotate the two halves of each head by position."""
-    s, hd = x.shape[1], x.shape[-1]
-    half = hd // 2
-    inv = 1.0 / theta ** (np.arange(half, dtype=np.float32) / half)
-    ang = np.arange(s, dtype=np.float32)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.cos(ang))[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang))[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def lm_hidden(p, tokens, cfg: dict, mode: str = "fp32"):
-    """tokens (B, S) -> last hidden states (B*S, d), float32."""
-    b, s = tokens.shape
-    d, nh, nkv = cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"]
-    hd = cfg.get("head_dim") or d // nh
-    group = nh // nkv
-    if cfg.get("norm") != "layernorm" or cfg.get("act") != "gelu":
-        raise ValueError("the LM reference covers layernorm + gelu models")
-    if len(p["segments"]) != 1:
-        raise ValueError("the LM reference covers one homogeneous segment")
-    f32 = functools.partial(jax.tree_util.tree_map,
-                            lambda a: a.astype(jnp.float32))
-
-    def layer(x, lp):
-        lp = f32(lp)
-        a = lp["attn"]
-        h = layer_norm(x, lp["norm1"])
-        q = einsum(mode, "bsd,de->bse", h, a["wq"])
-        k = einsum(mode, "bsd,de->bse", h, a["wk"])
-        v = einsum(mode, "bsd,de->bse", h, a["wv"])
-        if cfg.get("qkv_bias"):
-            q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-        q = rope(q.reshape(b, s, nh, hd), cfg["rope_theta"])
-        k = rope(k.reshape(b, s, nkv, hd), cfg["rope_theta"])
-        v = v.reshape(b, s, nkv, hd)
-        q = q.reshape(b, s, nkv, group, hd) / math.sqrt(hd)
-        scores = einsum(mode, "bqkgh,bckh->bkgqc", q, k)
-        causal = np.tril(np.ones((s, s), bool))
-        scores = jnp.where(causal, scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = einsum(mode, "bkgqc,bckh->bqkgh", probs, v).reshape(b, s, nh * hd)
-        x = stored(mode, x + einsum(mode, "bse,ed->bsd", o, a["wo"]))
-        h2 = layer_norm(x, lp["norm2"])
-        up = einsum(mode, "bsd,df->bsf", h2, lp["mlp"]["w_up"])
-        x = stored(mode, x + einsum(mode, "bsf,fd->bsd", gelu_tanh(up),
-                                    lp["mlp"]["w_down"]))
-        return x, None
-
-    x = stored(mode, p["embed"]["table"].astype(jnp.float32)[tokens])
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, p["segments"][0])
-    x = layer_norm(x, f32(p["final_norm"]))
-    return x.reshape(b * s, d)
-
-
-def hidden(p, batch, cfg: dict, mode: str = "fp32"):
-    """(h (T, d), labels (T,)) of a batch."""
-    return lm_hidden(p, batch["tokens"], cfg, mode), \
-        batch["labels"].reshape(-1)
+def stores(cfg: dict) -> bool:
+    """Whether the model holds its activations in a type below float32."""
+    return cfg.get("dtype") != "float32"
 
 
 # --- the sampler ------------------------------------------------------------
@@ -229,17 +168,25 @@ def _two_level(scores, n_valid: int, block: int):
 
 
 def draw(h, w, key, cfg: dict, proj=None):
-    """Negatives (ids (m,), exact log q (m,)) shared over the batch.
+    """Negatives and their exact log q: ids, logq (m,) shared over the
+    batch, or (T, m) per example, by ``cfg["sampler"]``.
     h: (T, d), w: (n, d)."""
-    alpha, m = cfg["sampler_alpha"], cfg["m_negatives"]
-    block, n = cfg["sampler_block"], cfg["vocab_size"]
     hq = h if proj is None else jnp.einsum("td,rd->tr", h, proj,
                                            precision=HIGHEST)
     wq = w if proj is None else jnp.einsum("nd,rd->nr", w, proj,
                                            precision=HIGHEST)
-    if cfg["sampler"] != "block-quadratic-shared":
-        raise ValueError(f"no reference for sampler {cfg['sampler']!r}")
-    t = h.shape[0]
+    if cfg["sampler"] == "block-quadratic-shared":
+        return _draw_shared(hq, wq, key, cfg)
+    if cfg["sampler"] == "block-quadratic":
+        return _draw_per_example(hq, wq, key, cfg)
+    raise ValueError(f"no reference for sampler {cfg['sampler']!r}")
+
+
+def _draw_shared(hq, wq, key, cfg: dict):
+    """m negatives shared over the batch, from the batch-summed kernel."""
+    alpha, m = cfg["sampler_alpha"], cfg["m_negatives"]
+    block, n = cfg["sampler_block"], cfg["vocab_size"]
+    t = hq.shape[0]
     hh = jnp.einsum("ti,tj->ij", hq, hq, precision=HIGHEST)
     quad = jnp.einsum("nr,rs,ns->n", wq, hh, wq, precision=HIGHEST)
     log_in, log_blk, logq = _two_level(alpha * quad + t, n, block)
@@ -253,14 +200,48 @@ def draw(h, w, key, cfg: dict, proj=None):
     return ids, logq[ids]
 
 
+#: examples whose per-class scores over the whole catalog are held at once
+#: by the per-example draw ((rows, n) float32 each)
+DRAW_ROWS = 16
+
+
+def _draw_per_example(hq, wq, key, cfg: dict):
+    """m i.i.d. negatives per example: the batch's key split over the
+    examples, each example's split into the block's key and the class's;
+    the exact per-class log q over all n classes, DRAW_ROWS examples at a
+    time."""
+    alpha, m = cfg["sampler_alpha"], cfg["m_negatives"]
+    block, n = cfg["sampler_block"], cfg["vocab_size"]
+
+    def one(args):
+        hq1, k = args
+        dots = jnp.einsum("nr,r->n", wq, hq1, precision=HIGHEST)
+        log_in, log_blk, logq = _two_level(alpha * jnp.square(dots) + 1.0,
+                                           n, block)
+        k_blk, k_in = jax.random.split(k)
+        blk = jax.random.categorical(k_blk, log_blk, shape=(m,))
+        within = jax.random.categorical(k_in, log_in[blk], axis=-1)
+        ids = (blk * block + within).astype(jnp.int32)
+        return ids, logq[ids]
+
+    keys = jax.random.split(key, hq.shape[0])
+    return jax.lax.map(one, (hq, keys), batch_size=DRAW_ROWS)
+
+
 def sampled_loss(h, w, labels, ids, logq, cfg: dict, mode: str = "fp32"):
-    """Per-target eq. 3 loss over [positive, m corrected negatives]."""
-    m = cfg["m_negatives"]
+    """Per-target eq. 3 loss over [positive, m corrected negatives]; ids
+    and logq (m,) shared over the batch or (T, m) per example.  With
+    ``abs_softmax`` the logits are |o| before the correction (eq. 11)."""
+    m, store = cfg["m_negatives"], stores(cfg)
+    pos = einsum(mode, "td,td->t", h, w[labels], store=store)
+    if ids.ndim == 1:
+        neg = einsum(mode, "td,md->tm", h, w[ids], store=store)
+        logq, hit = logq[None, :], ids[None, :] == labels[:, None]
+    else:
+        neg = einsum(mode, "td,tmd->tm", h, w[ids], store=store)
+        hit = ids == labels[:, None]
     if cfg.get("abs_softmax"):
-        raise ValueError("the reference covers the plain softmax")
-    pos = einsum(mode, "td,td->t", h, w[labels])
-    neg = einsum(mode, "td,md->tm", h, w[ids])
-    logq, hit = logq[None, :], ids[None, :] == labels[:, None]
+        pos, neg = jnp.abs(pos), jnp.abs(neg)
     neg = jnp.where(hit, -jnp.inf, neg - (logq + math.log(m)))
     allv = jnp.concatenate([pos[:, None], neg], axis=-1)
     return jax.nn.logsumexp(allv, axis=-1) - pos
@@ -269,18 +250,14 @@ def sampled_loss(h, w, labels, ids, logq, cfg: dict, mode: str = "fp32"):
 # --- two train steps --------------------------------------------------------
 
 
-def head_table(p):
-    """The class embeddings: the output head, or the embedding table where
-    the two are tied (there is no ``head`` leaf)."""
-    return p["head"]["w"] if "head" in p else p["embed"]["table"]
-
-
 def make_loss(cfg: dict, mode: str, half: bool):
+    family = families.load(cfg)
+
     def loss(p, batch, key, proj):
-        h, labels = hidden(p, batch, cfg, mode)
+        h, labels = family.hidden(p, batch, cfg, mode)
         if half:  # fault: half of the batch left out, mean over the rest
             h, labels = h[: h.shape[0] // 2], labels[: h.shape[0] // 2]
-        w = head_table(p).astype(jnp.float32)
+        w = family.head_table(p).astype(jnp.float32)
         ids, logq = draw(jax.lax.stop_gradient(h), jax.lax.stop_gradient(w),
                          key, cfg, proj)
         return jnp.mean(sampled_loss(h, w, labels, jax.lax.stop_gradient(ids),
@@ -301,10 +278,12 @@ def losses_under_keys(params, batch, keys, cfg: dict, proj=None) -> list:
     """The float32 loss of one batch at the given parameters under each of
     ``keys`` (each draws its own negatives): a forward pass of the
     backbone, and one draw and head per key."""
+    family = families.load(cfg)
+
     def run(p, b, ks, pr):
         p = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), p)
-        h, labels = hidden(p, b, cfg)
-        w = head_table(p).astype(jnp.float32)
+        h, labels = family.hidden(p, b, cfg)
+        w = family.head_table(p).astype(jnp.float32)
 
         def one(k):
             ids, logq = draw(h, w, k, cfg, pr)
@@ -387,7 +366,8 @@ def train_steps(params0, batches, keys, cfg: dict, opt: dict, *,
             name: np.array([float(_diff_norm(a, b))
                             for a, b in zip(leaves, mine)])
             for name, leaves in against.items()}
-        table = next(i for i, x in enumerate(mine) if x is head_table(g1))
+        table = next(i for i, x in enumerate(mine)
+                     if x is families.load(cfg).head_table(g1))
         moved = np.asarray(moved_rows(mine[table]))
         out["rows_gap"] = {name: rows_gap(moved_rows(leaves[table]), moved)
                            for name, leaves in against.items()}

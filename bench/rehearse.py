@@ -43,7 +43,7 @@ def rehearse_train(cell, sharding, modes=("fp32",)) -> None:
     import jax
     import jax.numpy as jnp
 
-    from bench import reference
+    from bench import families, reference
     from bench.kinds import train
     from repro.core.samplers import sampler_from_config
     from repro.models import api
@@ -71,13 +71,13 @@ def rehearse_train(cell, sharding, modes=("fp32",)) -> None:
     state, batch, key = (_on(sharding, t) for t in (state, batch, key))
     step = jax.jit(make_train_step(arch, ctx, opt), donate_argnums=(0,))
     print(f"[rehearse] {cell.name} train step, batch "
-          f"{train.targets_per_batch(mix)} targets: "
+          f"{train.targets_per_batch(mix, cfg)} targets: "
           f"{describe(step.lower(state, batch, key).compile())}", flush=True)
     proj = None
     if cfg.get("sampler_proj_rank"):
-        proj = jax.ShapeDtypeStruct(
-            (cfg["sampler_proj_rank"], cfg["d_model"]), jnp.float32,
-            sharding=sharding)
+        width = families.load(cfg).head_table(layout).shape[-1]
+        proj = jax.ShapeDtypeStruct((cfg["sampler_proj_rank"], width),
+                                    jnp.float32, sharding=sharding)
     p32 = _on(sharding, jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), layout))
     for mode in modes:

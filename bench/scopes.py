@@ -19,9 +19,14 @@ function of intervals and text, tested on small synthetic traces and HLO
 snippets (``tests/bench/test_bench_scopes.py``).
 
 A run's readings need the compiled step's HLO text beside its trace
-(``run["hlo_text"]``).  ``scripts/scope_split.py`` drives a train cell on
-the chip and prints them; the cell's own traced runs do not carry the
-text yet.
+(``run["hlo_text"]``): a train cell's traced run carries it, and each
+``bench/metrics/device_ms.train.<scope>.py`` reads one scope.  A Pallas
+kernel's custom call carries its ``kernel_metadata`` attribute as JSON
+with line breaks, so the instruction spans several lines of the text and
+its ``op_name`` lies on a later line than its name; ``splits`` reads the
+text with each instruction joined onto one line
+(``one_line_instructions``), else every kernel would read as
+``unscoped``.
 """
 from __future__ import annotations
 
@@ -39,6 +44,37 @@ REMAT_MARK = "rematted_computation"
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
 _WRAPPER = re.compile(r"^[\w.\-]+\((.*)\)$")
+
+
+def _open_braces(text: str) -> int:
+    """Braces opened less braces closed in ``text``, outside quotes."""
+    depth, quoted, escaped = 0, False, False
+    for ch in text:
+        if escaped:
+            escaped = False
+        elif ch == "\\":
+            escaped = True
+        elif ch == '"':
+            quoted = not quoted
+        elif not quoted:
+            depth += (ch == "{") - (ch == "}")
+    return depth
+
+
+def one_line_instructions(hlo_text: str) -> str:
+    """``hlo_text`` with every instruction that spans several lines (its
+    braces left open at the line's end) joined onto one line."""
+    lines: list[str] = []
+    open_ = False
+    for line in hlo_text.splitlines():
+        if open_:
+            lines[-1] += line
+        else:
+            lines.append(line)
+            if not _INSTRUCTION.match(line):
+                continue
+        open_ = _open_braces(lines[-1]) > 0
+    return "\n".join(lines)
 
 
 def op_paths(hlo_text: str) -> dict[str, str]:
@@ -114,10 +150,10 @@ def split_ns(ops, paths: dict, lo: float, hi: float) -> dict:
 def splits(run: dict) -> list[dict]:
     """``split_ns`` of each chip's ops in a traced run's window, mapped by
     the run's ``hlo_text``: the compiled step's optimized HLO text
-    (``Compiled.as_text()``).  Kept in ``run`` once computed, for the
-    next scope's reading."""
+    (``Compiled.as_text()``), its instructions joined onto one line each.
+    Kept in ``run`` once computed, for the next scope's reading."""
     if "scope_splits" not in run:
-        paths = op_paths(run["hlo_text"])
+        paths = op_paths(one_line_instructions(run["hlo_text"]))
         run["scope_splits"] = [
             split_ns(ops, paths, run["lo"], run["hi"])
             for ops in run["trace"].devices.values()]

@@ -6,7 +6,8 @@ anything the program made.  The layout (the pytree of leaf shapes and
 dtypes) is the program's parameter layout; the values follow the usual
 conventions, by the leaf's name:
 
-  * norm ``scale``: ones; norm ``bias`` and projection biases: zeros;
+  * norm ``scale``: ones; norm ``bias``, projection biases and the
+    biases ``b0``, ``b1``, ... of a tower's layers: zeros;
   * embedding table and output head: N(0, s) with the configuration's
     ``init_embed_std``;
   * every other matrix: N(0, 1 / fan_in), fan_in its second-to-last axis.
@@ -43,7 +44,7 @@ def _draw(key, path, sds, embed_std: float):
     name, where = _leaf_name(path), _path_str(path)
     if name == "scale":
         return jnp.ones(sds.shape, sds.dtype)
-    if name in BIAS_NAMES:
+    if name in BIAS_NAMES or (name[0] == "b" and name[1:].isdigit()):
         return jnp.zeros(sds.shape, sds.dtype)
     if where in ("embed/table", "head/w"):
         std = embed_std
