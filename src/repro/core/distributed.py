@@ -40,6 +40,7 @@ from repro.core.samplers import (
     categorical_rows,
     pool_log_inclusion,
 )
+from repro.core.topk import logits_topk
 from repro.kernels import ops
 
 Array = jax.Array
@@ -438,15 +439,14 @@ def sharded_logits_topk(w_local: Array, h: Array, k: int, *,
     w_local: (n/tp, d) local head shard; h: (T, d) replicated across the TP
     axis -> ids (T, k) int32 GLOBAL class ids, logits (T, k) fp32.
     Communication: one all-gather of (T, k) per-shard candidates — never a
-    gathered (T, n) logit tensor.  Ties resolve toward the lowest shard
-    (matching ``sharded_logits_argmax`` at k = 1)."""
-    logits = jnp.einsum("td,nd->tn", h.astype(jnp.float32),
-                        w_local.astype(jnp.float32))
-    if bias_local is not None:
-        logits = logits + bias_local[None, :]
+    gathered (T, n) logit tensor.  Each shard's top-k is ``logits_topk``
+    (``lax.top_k``'s answer, in two narrow stages at a large shard).  Ties
+    resolve toward the lowest shard (matching ``sharded_logits_argmax`` at
+    k = 1)."""
     n_local = w_local.shape[0]
     off = local_vocab_offset(n_local, axis_name)
-    local_best, local_arg = lax.top_k(logits, min(k, n_local))
+    local_best, local_arg = logits_topk(h, w_local, min(k, n_local),
+                                        bias=bias_local)
     local_ids = local_arg.astype(jnp.int32) + off
     all_best = lax.all_gather(local_best, axis_name, axis=1, tiled=True)
     all_ids = lax.all_gather(local_ids, axis_name, axis=1, tiled=True)

@@ -78,6 +78,7 @@ from repro.core.hierarchy import HierarchyStats
 from repro.core.midx import pc_bisect_perm  # noqa: F401  (canonical home
 # moved to core/midx.py — the midx posting lists and this serving index
 # share ONE balanced bisection; re-exported for existing callers)
+from repro.core.topk import logits_topk
 from repro.sharding.rules import gather_head_fd, head_fd_axes
 from repro.utils.misc import log2_int, next_pow2
 
@@ -534,12 +535,16 @@ def decode_topk(index: RetrievalIndex, h: Array, k: int,
 
 def dense_topk(w: Array, h: Array, k: int,
                n_valid: int | None = None) -> tuple[Array, Array]:
-    """O(n d) reference: exact top-k by dense logits (the old serving path)."""
-    logits = h.astype(jnp.float32) @ w.astype(jnp.float32).T
-    if n_valid is not None and n_valid < w.shape[0]:
-        logits = jnp.where(jnp.arange(w.shape[0]) < n_valid, logits,
-                           -jnp.inf)
-    vals, ids = lax.top_k(logits, k)
+    """Exact top-k by dense logits: the served decode without an index
+    (``engine.decode_topk`` on one device), and the oracle of the
+    ``recall_at_k`` measurements.
+
+    O(n d): h . W^T in float32 at the default precision, rows at or past
+    ``n_valid`` masked to -inf, then the top k by ``topk.logits_topk`` --
+    the same values and ids as ``lax.top_k`` over the logits, found in two
+    narrow stages at a large n instead of one sort of the whole row
+    (DESIGN.md §5)."""
+    vals, ids = logits_topk(h, w, k, n_valid=n_valid)
     return ids.astype(jnp.int32), vals
 
 

@@ -2,22 +2,27 @@
 arrays ride the vocab-sharded P('model') layout, each shard beams over its
 local subtree, and the cross-shard merge reproduces the dense sharded
 argmax/top-k bit-identically at full beam — on an untrained AND a
-briefly-trained model, including non-divisible vocab padding."""
+briefly-trained model, including non-divisible vocab padding.  The dense
+sharded top-k, whose per-shard step is the two-stage ``logits_topk``,
+equals a global ``lax.top_k`` bit for bit, ties included."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
+from repro.core import distributed, topk
 from repro.launch.mesh import make_debug_mesh
 from repro.models import api
 from repro.models.transformer import init_cache
 from repro.optim import make_optimizer
 from repro.serve import engine, retrieval
 from repro.serve.engine import make_decode_step, make_topk_step
-from repro.sharding.rules import mesh_ctx
+from repro.sharding.rules import head_fd_axes, mesh_ctx
 from repro.train.step import (
     export_retrieval_index,
     init_train_state,
@@ -98,5 +103,67 @@ ids, logits, _ = jax.jit(make_topk_step(cfg, mctx, K, index=index))(
     state.params, tok, caches2, pos)
 np.testing.assert_array_equal(np.asarray(ids[:, 0]), np.asarray(nxt))
 print("topk step top-1 == greedy decode on 2x4 mesh")
+
+
+# --- the per-shard two-stage top-k (core/topk.py) ---------------------------
+# Quarter-valued operands make every logit exact whatever the summation
+# order, so the global lax.top_k over the same logits is the oracle, bit
+# for bit, ties (to the lowest id) included.
+mdl = mctx.model_axis
+TP = mesh.shape[mdl]
+
+
+def quarters(key, shape):
+    return jnp.round(jax.random.normal(key, shape) * 2) / 4
+
+
+def sharded_topk(w, h, k, n_valid):
+    v_l = w.shape[0] // TP
+
+    def island(w_l, h_l):
+        my = jax.lax.axis_index(mdl)
+        bias = jnp.where(jnp.arange(v_l) < n_valid - my * v_l, 0.0, -jnp.inf)
+        return distributed.sharded_logits_topk(w_l, h_l, k, axis_name=mdl,
+                                               bias_local=bias)
+
+    return jax.jit(jax.shard_map(
+        island, mesh=mesh, check_vma=False, in_specs=(P(mdl, None), P()),
+        out_specs=(P(), P())))(w, h)
+
+
+def oracle(w, h, k, n_valid):
+    logits = jnp.where(jnp.arange(w.shape[0]) < n_valid, h @ w.T, -jnp.inf)
+    vals, ids = jax.lax.top_k(logits, k)
+    return ids, vals
+
+
+# (shard width, k, valid rows): a split shard whose width G does not divide;
+# k = 1 (plain: a max reduction); a split shard and a -inf tail past
+# n_valid; a plain shard
+for v_l, k, n_valid in [(10_000, 8, 40_000), (5_000, 1, 20_000),
+                        (10_240, 8, 35_000), (2_000, 8, 8_000)]:
+    split = topk.group_size(v_l, k) is not None
+    assert split == (v_l >= 5_000 and k > 1), (v_l, k)
+    w = quarters(jax.random.PRNGKey(v_l), (TP * v_l, 16))
+    h = quarters(jax.random.PRNGKey(k), (6, 16))
+    ids, vals = sharded_topk(w, h, k, n_valid)
+    want_ids, want_vals = oracle(w, h, k, n_valid)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(want_vals))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(want_ids))
+    print(f"sharded top-{k}, {v_l} rows a shard (split {split}): "
+          "== global lax.top_k")
+
+# the engine's mesh decode over the head as given: G does not divide a shard
+cfg_big = cfg.reduced(vocab_size=39_990, d_model=16)
+head = jax.device_put(quarters(jax.random.PRNGKey(3), (TP * 10_000, 16)),
+                      NamedSharding(mesh, P(mdl, head_fd_axes(mctx))))
+decode = engine.make_decode_fn(cfg_big, mctx, head, K)
+assert decode.args[0] is head and topk.group_size(10_000, K) is not None
+h = quarters(jax.random.PRNGKey(4), (B, 16))
+ids, vals = jax.jit(lambda fn, h: fn(None, h))(decode, h)
+want_ids, want_vals = oracle(head, h, K, cfg_big.vocab_size)
+np.testing.assert_array_equal(np.asarray(vals), np.asarray(want_vals))
+np.testing.assert_array_equal(np.asarray(ids), np.asarray(want_ids))
+print("mesh decode over an uneven head == global lax.top_k")
 
 print("DECODE TOPK CHECKS PASSED")
